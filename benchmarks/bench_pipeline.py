@@ -214,8 +214,8 @@ def bench_fabric(pipes_list, devices_list, n_pkts, chunk, window, capacity,
     as exact-gated ``shard_invariance_identical`` rows.  Any divergence
     exits non-zero.  Timing rows (``fabric/.../pps``) record the scaling
     trajectory; ``devices_effective`` in the derived field exposes the
-    guarded fallback (requested counts that didn't divide the pipe axis or
-    exceeded visible devices ran replicated on one device).
+    guarded fallback (requested counts that didn't divide the pipe axis
+    ran replicated on one device; counts beyond the visible devices raise).
     """
     from repro.switchsim import fabric
     devices_list = sorted(set(devices_list) | {1})

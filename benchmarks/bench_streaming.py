@@ -70,13 +70,19 @@ def _trace_mb(steps: int, chunk: int, pmax: int) -> float:
     return steps * chunk * (pmax + 64) / (1024.0 * 1024.0)
 
 
-def bench(g: dict, oracle: bool, backend=None):
+def workload(g: dict, seed: int = 0):
+    """The streamed deployment at geometry ``g``: (ParkConfig, chain,
+    source) — a NAT behind a recirculating switch under diurnal load."""
     cfg = ParkConfig(capacity=g["capacity"], max_exp=2, pmax=g["pmax"],
                      recirculation=True, recirc_frac=0.25)
-    chain = Chain((Nat(),))
     source = SyntheticSource(
-        steps=g["steps"], chunk=g["chunk"], pmax=g["pmax"], seed=0,
+        steps=g["steps"], chunk=g["chunk"], pmax=g["pmax"], seed=seed,
         flows=g["flows"], load=DiurnalLoad(period=g["load_period"]))
+    return cfg, Chain((Nat(),)), source
+
+
+def bench(g: dict, oracle: bool, backend=None):
+    cfg, chain, source = workload(g)
 
     def run(steps):
         import dataclasses

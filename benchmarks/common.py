@@ -51,9 +51,13 @@ def make_parser(description: str) -> argparse.ArgumentParser:
 
 
 def check_flags(ap: argparse.ArgumentParser, args) -> None:
-    """Shared post-parse validation; call right after ``parse_args``."""
+    """Shared post-parse validation and set-up; call right after
+    ``parse_args``.  Also turns on the persistent compilation cache
+    (``repro.compile_cache``)."""
     if args.no_verify and args.oracle:
         ap.error("--no-verify and --oracle are mutually exclusive")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
 
 def single_backend(ap: argparse.ArgumentParser, args) -> str | None:

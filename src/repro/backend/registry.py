@@ -32,30 +32,30 @@ class Primitive:
     pallas: Callable
 
 
-def _pallas_crc16_tag(ti, clk, *, interpret: bool = True):
+def _pallas_crc16_tag(ti, clk, *, interpret: bool):
     from repro.kernels.crc16.ops import crc16_tag_kernel_op
     return crc16_tag_kernel_op(ti, clk, interpret=interpret)
 
 
-def _pallas_acl_match(src_ip, rules, *, interpret: bool = True):
+def _pallas_acl_match(src_ip, rules, *, interpret: bool):
     from repro.kernels.acl_match.ops import acl_match
     return acl_match(src_ip, rules, interpret=interpret)
 
 
 def _pallas_maglev_select(src_ip, dst_ip, src_port, dst_port, proto,
-                          table, backend_ips, *, interpret: bool = True):
+                          table, backend_ips, *, interpret: bool):
     from repro.kernels.maglev.ops import maglev_select
     return maglev_select(src_ip, dst_ip, src_port, dst_port, proto,
                          table, backend_ips, interpret=interpret)
 
 
 def _pallas_payload_store(table, payload, idx, enb, *,
-                          interpret: bool = True):
+                          interpret: bool):
     from repro.kernels.payload_store.ops import payload_store
     return payload_store(table, payload, idx, enb, interpret=interpret)
 
 
-def _pallas_payload_fetch(table, idx, mask, *, interpret: bool = True):
+def _pallas_payload_fetch(table, idx, mask, *, interpret: bool):
     from repro.kernels.payload_fetch.ops import payload_fetch
     return payload_fetch(table, idx, mask, interpret=interpret)
 

@@ -34,13 +34,7 @@ def jax_backend_initialized() -> bool:
     jax_mod = sys.modules.get("jax")
     if jax_mod is None:
         return False
-    try:
-        backends = jax_mod._src.xla_bridge._backends
-    except AttributeError:
-        # unknown jax internals: assume the worst (initialized) so callers
-        # fail loudly rather than silently run on the wrong device count
-        return True
-    return bool(backends)
+    return bool(jax_mod._src.xla_bridge._backends)
 
 
 def force_host_devices(n: int) -> None:

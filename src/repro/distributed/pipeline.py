@@ -18,7 +18,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -48,8 +47,8 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, stage_axis: str = "pod"):
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
     xspec = P(*([None] * x.ndim))
 
-    @partial(shard_map, mesh=mesh, in_specs=(pspec, xspec),
-             out_specs=xspec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(pspec, xspec),
+             out_specs=xspec, check_vma=False)
     def run(params_local, x_all):
         stage_id = jax.lax.axis_index(stage_axis)
         is_first = stage_id == 0

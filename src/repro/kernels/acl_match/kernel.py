@@ -25,7 +25,7 @@ def _acl_kernel(ip_ref, rules_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
-def acl_match_kernel(ip, rules, *, bt: int = 8, interpret: bool = True):
+def acl_match_kernel(ip, rules, *, bt: int = 8, interpret: bool):
     """ip: (N, LANES) int32; rules: (1, R) int32 -> (N, LANES) int32 0/1."""
     n, lanes = ip.shape
     assert lanes == LANES and n % bt == 0

@@ -10,7 +10,7 @@ from repro.kernels.acl_match.kernel import LANES, acl_match_kernel
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def acl_match(src_ip, rules, interpret: bool = True):
+def acl_match(src_ip, rules, *, interpret: bool):
     """src_ip: (B,) int32; rules: (R,) int32 -> (B,) bool."""
     b = src_ip.shape[0]
     tile = LANES * 8

@@ -37,7 +37,7 @@ def _crc_kernel(ti_ref, clk_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
-def crc16_kernel(ti, clk, *, bt: int = 8, interpret: bool = True):
+def crc16_kernel(ti, clk, *, bt: int = 8, interpret: bool):
     """ti, clk: (N, LANES) int32 -> (N, LANES) int32 CRCs."""
     n, lanes = ti.shape
     assert lanes == LANES and n % bt == 0, (ti.shape, bt)

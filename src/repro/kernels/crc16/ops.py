@@ -10,7 +10,7 @@ from repro.kernels.crc16.kernel import LANES, crc16_kernel
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def crc16_tag_kernel_op(ti, clk, interpret: bool = True):
+def crc16_tag_kernel_op(ti, clk, *, interpret: bool):
     b = ti.shape[0]
     tile = LANES * 8
     pad = (-b) % tile

@@ -11,7 +11,7 @@ from repro.kernels.maglev.kernel import LANES, maglev_kernel
 
 @partial(jax.jit, static_argnames=("interpret",))
 def maglev_select(src_ip, dst_ip, src_port, dst_port, proto, table,
-                  backend_ips, interpret: bool = True):
+                  backend_ips, *, interpret: bool):
     """Per-packet backend VIP selection; all inputs (B,) int32."""
     b = src_ip.shape[0]
     tile = LANES * 8
@@ -22,6 +22,6 @@ def maglev_select(src_ip, dst_ip, src_port, dst_port, proto, table,
 
     out = maglev_kernel(
         prep(src_ip), prep(dst_ip), prep(src_port), prep(dst_port),
-        prep(proto), table.astype(jnp.int32)[None, :],
-        backend_ips.astype(jnp.int32)[None, :], interpret=interpret)
+        prep(proto), table.astype(jnp.int32),
+        backend_ips.astype(jnp.int32), interpret=interpret)
     return out.reshape(-1)[:b]

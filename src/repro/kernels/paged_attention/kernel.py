@@ -66,7 +66,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, out_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, lengths,
-                                  *, interpret: bool = True):
+                                  *, interpret: bool):
     """q: (B,K,G,E); k_pages/v_pages: (P, page, K, E);
     page_table: (B, MP) int32 (-1 pad); lengths: (B,) int32."""
     b, kh, g, e = q.shape

@@ -11,7 +11,7 @@ from repro.kernels.paged_attention.kernel import paged_decode_attention_kernel
 
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                           interpret: bool = True):
+                           *, interpret: bool):
     """q: (B, K, G, E); k_pages/v_pages: (P, page, K, E);
     page_table: (B, MP) int32 with -1 padding; lengths: (B,) int32."""
     return paged_decode_attention_kernel(

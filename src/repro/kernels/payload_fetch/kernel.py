@@ -47,7 +47,7 @@ def _fetch_kernel(idx_ref, mask_ref, table_in_ref, out_ref, table_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def payload_fetch_kernel(table, idx, mask, *, bt: int = DEFAULT_BT,
-                         interpret: bool = True):
+                         interpret: bool):
     """table: (M, W) int32; idx/mask: (B,).  Returns (gathered, new_table)."""
     m, w = table.shape
     b = idx.shape[0]

@@ -12,7 +12,7 @@ from repro.kernels.payload_store.ops import _pad_lanes, _to_bytes, _to_words
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def payload_fetch(table_u8, idx, mask, interpret: bool = True):
+def payload_fetch(table_u8, idx, mask, *, interpret: bool):
     """Gather+clear parked rows.  Returns (parked (B, bytes) u8, new table)."""
     m, nbytes = table_u8.shape
     assert nbytes % 4 == 0, nbytes

@@ -47,7 +47,7 @@ def _store_kernel(idx_ref, enb_ref, payload_ref, table_in_ref, table_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def payload_store_kernel(table, payload, idx, enb, *, bt: int = DEFAULT_BT,
-                         interpret: bool = True):
+                         interpret: bool):
     """table: (M, W) int32, payload: (B, W) int32, idx: (B,), enb: (B,)."""
     m, w = table.shape
     b, _ = payload.shape

@@ -38,7 +38,7 @@ def _pad_lanes(x):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def payload_store(table_u8, payload_u8, idx, enb, interpret: bool = True):
+def payload_store(table_u8, payload_u8, idx, enb, *, interpret: bool):
     """Scatter parked payload rows: table[idx[b]] = payload[b] where enb[b]."""
     m, nbytes = table_u8.shape
     assert nbytes % 4 == 0, nbytes

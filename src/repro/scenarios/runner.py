@@ -39,6 +39,7 @@ from repro.switchsim.results import flat_summary
 from repro.switchsim.simulate import simulate_loop
 from repro.switchsim.telemetry import LinkTelemetry, sum_telemetry
 from repro.core import counters as C
+from repro.core.packet import PacketBatch
 
 
 @dataclasses.dataclass
@@ -65,6 +66,10 @@ class ScenarioResult:
     # the prepared traffic/chain/traces this result was computed from;
     # verify_oracle reuses it instead of regenerating (repr-noise excluded)
     prepared: "_Prepared" = dataclasses.field(default=None, repr=False)
+    # the point's merged output, (P, T, rows, ...) device arrays: the
+    # engine's own arrays (sharding included) when the point ran alone in
+    # its group, its pipe slice of them otherwise
+    merged: PacketBatch = dataclasses.field(default=None, repr=False)
 
     @property
     def peak_occupancy(self) -> int:
@@ -189,6 +194,9 @@ def run_matrix(specs, time_runs: bool = False,
                 group_size=len(members),
                 group_wall_s=group_wall,
                 prepared=p,
+                merged=(res.merged if len(members) == 1 else
+                        jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi],
+                                     res.merged)),
             )
         assert offset == len(res.per_pipe_counters)
     return results
@@ -198,9 +206,10 @@ class OracleMismatch(AssertionError):
     """Engine diverged from the host-loop reference on a scenario point."""
 
 
-def verify_oracle(result: ScenarioResult, faults=True) -> None:
+def verify_oracle(result: ScenarioResult, faults=True,
+                  pipes=None) -> None:
     """Assert engine ≡ host loop (counters + telemetry + NF counters) for
-    one point.
+    one point, on every pipe or on the pipe indices in ``pipes``.
 
     Re-runs ``simulate_loop`` per pipe on the pipe's flat trace (dead
     padding rows are no-ops for the loop exactly as for the engine), on
@@ -231,7 +240,7 @@ def verify_oracle(result: ScenarioResult, faults=True) -> None:
     # (devices that didn't divide the pipe axis ran replicated on shard 0)
     per_shard = (spec.pipes // spec.devices
                  if spec.pipes % spec.devices == 0 else spec.pipes)
-    for pipe in range(spec.pipes):
+    for pipe in (range(spec.pipes) if pipes is None else pipes):
         shard = pipe // max(per_shard, 1)
         where = (f"{spec.name} pipe {pipe} (shard {shard}/{spec.devices})"
                  if spec.devices > 1 else f"{spec.name} pipe {pipe}")

@@ -441,7 +441,7 @@ def run_pipes(
     ``switchsim.fabric`` (mesh axis ``"switch"``, DESIGN.md §12).  Results
     are bit-identical for any device count (shard-count invariance); the
     request falls back to 1 with a warning when the pipe count does not
-    divide it or fewer devices are visible.
+    divide it, and raises when fewer devices are visible.
     """
     backend = coerce_backend(backend)
     traces = _as_pipe_traces(traces)

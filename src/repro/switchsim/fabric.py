@@ -19,9 +19,9 @@ module puts a ``jax.sharding`` mesh under it:
   * ``resolve_devices(pipes, devices)`` is the guarded
     fallback-to-replication (``distributed.sharding.divides_axis``, the
     same predicate the model-parallel rules use): when the pipe count
-    does not divide the requested device count, or fewer devices are
-    visible than requested, the run warns and executes replicated on one
-    device — never padded, never crashed.
+    does not divide the requested device count, the run warns and
+    executes replicated on one device — never padded.  Fewer visible
+    devices than requested is an error, never a silent single-device run.
 
 **Shard-count invariance is the correctness contract**: the same
 ``ScenarioSpec`` run on 1, 2 or 8 devices yields bit-identical counters,
@@ -51,8 +51,7 @@ from __future__ import annotations
 import warnings
 
 import jax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import AxisType, Mesh, PartitionSpec
 
 from repro.distributed.sharding import axis_size, divides_axis
 
@@ -68,33 +67,36 @@ def fabric_devices() -> int:
 
 
 def switch_mesh(devices: int) -> Mesh:
-    """1-D ``("switch",)`` mesh over the first ``devices`` devices."""
-    return jax.make_mesh((devices,), (SWITCH_AXIS,))
+    """1-D ``("switch",)`` mesh over the first ``devices`` devices.
+
+    The axis is ``Auto``-typed: the engine's host-side finalization indexes
+    single pipes out of the sharded outputs (``a[p]``), a gather that an
+    ``Explicit`` axis refuses to resolve without an ``out_sharding``."""
+    return jax.make_mesh((devices,), (SWITCH_AXIS,),
+                         axis_types=(AxisType.Auto,))
 
 
 def resolve_devices(pipes: int, devices: int | None) -> int:
-    """Guarded fallback-to-replication: the device count a ``pipes``-wide
-    run will actually shard over.
+    """The device count a ``pipes``-wide run will actually shard over.
 
     Returns ``devices`` when it is usable (>1, visible, and dividing the
     pipe axis — ``distributed.sharding.divides_axis``, the same guard the
-    model-parallel rules apply to weight dims); otherwise warns and
-    returns 1, i.e. the replicated single-device path.  Shard-count
-    invariance makes the fallback safe: results are bit-identical either
-    way, only wall-clock changes.
+    model-parallel rules apply to weight dims).  A pipe count that does not
+    divide the devices warns and returns 1, the replicated single-device
+    path; shard-count invariance makes that safe (bit-identical results,
+    only wall-clock changes).  Asking for more devices than are visible
+    raises: running such a request on one device would hide the missing
+    hardware behind a result that looks sharded.
     """
     if devices is None or devices <= 1:
         return 1
     avail = fabric_devices()
     if devices > avail:
-        warnings.warn(
+        raise ValueError(
             f"fabric: {devices} devices requested but only {avail} "
-            f"visible — running replicated on one device.  On CPU, force "
-            f"host devices before jax initializes "
+            f"visible.  On CPU, force host devices before jax initializes "
             f"(repro.distributed.force_host_devices({devices}) or "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={devices}).",
-            stacklevel=2)
-        return 1
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={devices}).")
     if not divides_axis(pipes, devices):
         warnings.warn(
             f"fabric: pipe axis of {pipes} does not divide over "
@@ -125,8 +127,8 @@ def shard_over_switch(run, devices: int):
     mesh = switch_mesh(devices)
     assert axis_size(mesh, SWITCH_AXIS) == devices
     spec = PartitionSpec(SWITCH_AXIS)
-    # check_rep=False: the body is a pure per-pipe map with no collectives
-    # and no replicated outputs, so the replication checker has nothing to
+    # check_vma=False: the body is a pure per-pipe map with no collectives
+    # and no replicated outputs, so the varying-axes checker has nothing to
     # prove and only adds tracing overhead on wide fabrics.
-    return shard_map(run, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                     out_specs=spec, check_rep=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec, spec),
+                         out_specs=spec, check_vma=False)

@@ -342,20 +342,26 @@ def test_rpl005_scoped_to_hot_dirs(tmp_path):
     assert run_replint(tmp_path, files, "RPL005") == []
 
 
-def test_rpl005_fires_inside_shard_map_body_under_distributed(tmp_path):
+@pytest.mark.parametrize("wrap", [
+    "run = jax.shard_map(body, mesh=None, in_specs=None, out_specs=None)",
+    "run = partial(jax.shard_map, mesh=None, in_specs=None,\n"
+    "              out_specs=None)(body)",
+])
+def test_rpl005_fires_inside_shard_map_body_under_distributed(tmp_path,
+                                                              wrap):
     # the fabric-sharding scope extension (DESIGN.md §12): shard_map bodies
     # are traced code, and distributed/ is a hot dir now
-    files = {"distributed/fab.py": """\
+    files = {"distributed/fab.py": textwrap.dedent("""\
+        from functools import partial
+
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental.shard_map import shard_map
 
         def body(x):
             return np.asarray(jnp.sum(x))
 
-        run = shard_map(body, mesh=None, in_specs=None, out_specs=None)
-        """}
+        """) + wrap + "\n"}
     findings = run_replint(tmp_path, files, "RPL005")
     assert len(findings) == 1 and "np.asarray" in findings[0].message
 

@@ -165,11 +165,15 @@ class TestGoldenVectors:
     # make_udp_batch(key(42), 24, 300, pmax=512): Firewall(rules=src_ip[:5])
     # drop mask and MaglevLB() dst_ip rewrites
     FW_GOLDEN = [1, 1, 1, 1, 1] + [0] * 19
-    LB_GOLDEN = [167772420, 167772421, 167772416, 167772421, 167772416,
-                 167772416, 167772417, 167772422, 167772419, 167772423,
-                 167772418, 167772420, 167772416, 167772416, 167772417,
-                 167772417, 167772423, 167772420, 167772422, 167772418,
-                 167772423, 167772422, 167772418, 167772416]
+    # Re-captured under JAX's current default jax_threefry_partitionable=True.
+    # The previous vector (167772420, 167772421, 167772416, ...) is still
+    # reproduced exactly with that flag set to False: only the random input
+    # batch moved when JAX changed the default, not the Maglev hash or table.
+    LB_GOLDEN = [167772419, 167772422, 167772417, 167772421, 167772419,
+                 167772416, 167772421, 167772423, 167772420, 167772421,
+                 167772420, 167772423, 167772420, 167772420, 167772418,
+                 167772423, 167772417, 167772421, 167772417, 167772417,
+                 167772419, 167772417, 167772417, 167772419]
 
     @pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
     def test_crc16_tag_unchanged(self, backend):

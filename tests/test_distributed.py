@@ -27,13 +27,15 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900):
 
 PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro import configs
 from repro.configs.reduced import reduced
 from repro.distributed.sharding import Rules
 from repro.models.lm import LM
 from repro.training.train_step import TrainConfig, init_train_state, train_step
 from repro.training.optimizer import AdamWConfig
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = reduced(configs.get("minitron-8b"))
 lm = LM(cfg)
 tcfg = TrainConfig(adamw=AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=10))
@@ -110,7 +112,8 @@ with mesh:
 d = tempfile.mkdtemp()
 ckpt.save(d, 1, st)
 
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 with mesh2:
     rules2 = Rules(cfg, mesh2)
     template = jax.eval_shape(lambda: init_train_state(lm, jax.random.key(0)))
@@ -127,13 +130,13 @@ def test_quantized_psum_shard_map():
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.training.compression import quantized_psum
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.key(0), (8, 64))
 
-@partial(shard_map, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("data", None),
+         out_specs=P("data", None))
 def f(xs):
     return quantized_psum(xs, "data")[None] * jnp.ones((xs.shape[0], 1))
 
@@ -149,8 +152,10 @@ print("quantized psum OK", err)
 def test_pipeline_parallel_matches_sequential():
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.distributed.pipeline import pipeline_apply, sequential_apply
-mesh = jax.make_mesh((4, 2), ("pod", "model"))
+mesh = jax.make_mesh((4, 2), ("pod", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 # toy 4-stage MLP pipeline
 k = jax.random.key(0)
 ws = jax.random.normal(k, (4, 16, 16)) * 0.3

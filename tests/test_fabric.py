@@ -13,7 +13,6 @@ import os
 import subprocess
 import sys
 import textwrap
-import warnings
 
 import pytest
 
@@ -123,16 +122,16 @@ print("group OK")
 
 
 def test_more_devices_than_visible_falls_back():
-    """Requesting more devices than visible warns and runs replicated."""
+    """Requesting more devices than visible raises: no silent run on
+    fewer devices than asked for."""
     run_sub(PRELUDE + """
-with warnings.catch_warnings(record=True) as w:
-    warnings.simplefilter("always")
-    r = S.run_matrix([point(2, 4)])[0]
-assert any("only 2 visible" in str(x.message) for x in w), \
-    [str(x.message) for x in w]
-ref = S.run_matrix([point(2, 1)])[0]
-assert same(r, ref)
-print("visibility fallback OK")
+try:
+    S.run_matrix([point(2, 4)])
+except ValueError as e:
+    assert "only 2 visible" in str(e), e
+else:
+    raise SystemExit("oversubscribed fabric run did not raise")
+print("visibility error OK")
 """, devices=2)
 
 
@@ -200,14 +199,12 @@ def test_spec_devices_validation_and_compile_key():
 
 def test_resolve_devices_guards():
     """resolve_devices: trivial counts short-circuit without touching jax;
-    non-dividing and oversubscribed requests fall back with a warning."""
+    oversubscribed requests raise."""
     from repro.switchsim import fabric
 
     assert fabric.resolve_devices(8, None) == 1
     assert fabric.resolve_devices(8, 1) == 1
     assert fabric.resolve_devices(8, 0) == 1
-    # the single in-process device: 2 > visible -> warn + fallback
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert fabric.resolve_devices(8, 2) == 1
-    assert any("visible" in str(x.message) for x in w)
+    # the single in-process device: 2 > visible -> error
+    with pytest.raises(ValueError, match="visible"):
+        fabric.resolve_devices(8, 2)

@@ -1,5 +1,6 @@
 """Per-kernel allclose vs the pure-jnp oracle, with shape/dtype sweeps
-(interpret=True executes the Pallas kernel bodies on CPU)."""
+(interpret=True, passed explicitly, executes the Pallas kernel bodies on
+CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,7 +35,7 @@ def test_payload_store_sweep(m, nbytes, b):
     idx = jax.random.permutation(ks[2], m)[:b] if b <= m else \
         jnp.arange(b) % m
     enb = jax.random.bernoulli(ks[3], 0.7, (b,))
-    got = payload_store(table, payload, idx, enb)
+    got = payload_store(table, payload, idx, enb, interpret=True)
     want_w = payload_store_ref(_to_words(table), _to_words(payload), idx, enb)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(_to_bytes(want_w, nbytes)))
@@ -48,7 +49,7 @@ def test_payload_fetch_sweep(m, nbytes, b):
     idx = jax.random.permutation(ks[1], m)[:b] if b <= m else \
         jnp.arange(b) % m
     mask = jax.random.bernoulli(ks[2], 0.6, (b,))
-    got_rows, got_table = payload_fetch(table, idx, mask)
+    got_rows, got_table = payload_fetch(table, idx, mask, interpret=True)
     want_rows_w, want_table_w = payload_fetch_ref(_to_words(table), idx, mask)
     np.testing.assert_array_equal(
         np.asarray(got_rows), np.asarray(_to_bytes(want_rows_w, nbytes)))
@@ -62,7 +63,7 @@ def test_crc16_sweep(n):
     ti = jax.random.randint(ks[0], (n,), 0, 1 << 16, dtype=jnp.int32)
     clk = jax.random.randint(ks[1], (n,), 1, 1 << 16, dtype=jnp.int32)
     np.testing.assert_array_equal(
-        np.asarray(crc16_tag_kernel_op(ti, clk)),
+        np.asarray(crc16_tag_kernel_op(ti, clk, interpret=True)),
         np.asarray(crc16_tag_ref(ti, clk)))
 
 
@@ -78,8 +79,9 @@ def test_acl_match_sweep(b, r):
     ks = jax.random.split(jax.random.key(3), 2)
     ips = jax.random.randint(ks[0], (b,), 0, 50, dtype=jnp.int32)
     rules = jax.random.randint(ks[1], (r,), 0, 50, dtype=jnp.int32)
-    np.testing.assert_array_equal(np.asarray(acl_match(ips, rules)),
-                                  np.asarray(acl_match_ref(ips, rules)))
+    np.testing.assert_array_equal(
+        np.asarray(acl_match(ips, rules, interpret=True)),
+        np.asarray(acl_match_ref(ips, rules)))
 
 
 @pytest.mark.parametrize("b", [3, 300])
@@ -90,7 +92,7 @@ def test_maglev_sweep(b):
     st = lb.init_state()
     p = make_udp_batch(jax.random.key(4), b, 300, pmax=512)
     got = maglev_select(p.src_ip, p.dst_ip, p.src_port, p.dst_port, p.proto,
-                        st["table"], st["backend_ips"])
+                        st["table"], st["backend_ips"], interpret=True)
     want = maglev_select_ref(p.src_ip, p.dst_ip, p.src_port, p.dst_port,
                              p.proto, st["table"], st["backend_ips"])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -115,7 +117,7 @@ def test_paged_attention_sweep(b, k, g, e, page, mp):
         pt[i, :n] = rng.choice(npages, n, replace=False)
         lengths[i] = rng.integers(1, n * page + 1)
     got = paged_decode_attention(q, kp, vp, jnp.asarray(pt),
-                                 jnp.asarray(lengths))
+                                 jnp.asarray(lengths), interpret=True)
     want = paged_decode_attention_ref(q, kp, vp, jnp.asarray(pt),
                                       jnp.asarray(lengths))
     np.testing.assert_allclose(np.asarray(got, np.float32),
