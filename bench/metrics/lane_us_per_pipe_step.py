@@ -1,0 +1,11 @@
+"""Device time of the recirculation lane (``engine.lane``: the second pass,
+the lane's admission and the lane rows joined to the NF-bound chunk) per
+pipe-step of the profiled slice, in us: the counted device ops whose op
+name carries the stage's scope, over the slice's pipe-steps.  Not read
+from a trace that lost kernel launches, nor from a program without the
+engine's stage scopes."""
+from bench import stages
+
+
+def read(run):
+    return stages.stage_us_per_pipe_step(run, "lane")

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.packet import OP_DROP, PacketBatch
@@ -39,7 +40,11 @@ class Chain:
         total_cycles = 0.0
         new_states = []
         for nf, st in zip(self.nfs, states):
-            st, pkts, drop, cycles = nf(st, pkts, backend=backend, ctx=ctx)
+            # each NF's ops carry ``nf.<class>`` in their op names, so a
+            # device trace tells the NFs apart (DESIGN.md §14)
+            with jax.named_scope(f"nf.{type(nf).__name__}"):
+                st, pkts, drop, cycles = nf(st, pkts, backend=backend,
+                                            ctx=ctx)
             dropped = dropped | drop
             total_cycles += cycles
             new_states.append(st)

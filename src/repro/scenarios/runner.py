@@ -26,6 +26,7 @@ every benchmark asserts it the same way.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -114,6 +115,55 @@ def _cat_pipe_axis(traces_list):
         lambda *xs: jnp.concatenate(xs, axis=0), *traces_list)
 
 
+def _grouped(specs):
+    """The prepared points and their compile groups (``compile_key`` ->
+    member indices), under the ``repro.prepare`` and ``repro.dispatch``
+    spans (DESIGN.md §14)."""
+    prepared = []
+    for s in specs:
+        with jax.profiler.TraceAnnotation("repro.prepare"):
+            prepared.append(_prepare(s))
+    with jax.profiler.TraceAnnotation("repro.dispatch"):
+        groups: dict = {}
+        for i, p in enumerate(prepared):
+            steps = jax.tree.leaves(p.traces)[0].shape[1]
+            key = compile_key(p.spec, p.chain, steps)
+            groups.setdefault(key, []).append(i)
+    return prepared, groups
+
+
+def _group_args(key, members, prepared) -> tuple[tuple, dict]:
+    """``engine.run_pipes``'s arguments for one compile group: its
+    members' traces and fault masks stacked on one pipe axis."""
+    (cfg, chain, window, _chunk, _steps, _pmax, explicit_drops,
+     _lane, backend, devices) = key
+    with jax.profiler.TraceAnnotation("repro.dispatch"):
+        stacked = _cat_pipe_axis([prepared[i].traces for i in members])
+        # fault masks ride the same stacked pipe axis as the traces —
+        # healthy members contribute all-True columns, so one compiled
+        # program serves faulted and healthy points alike (DESIGN.md §10)
+        stacked_faults = F.concat([prepared[i].faults for i in members])
+    # ``devices`` shards the group's *concatenated* pipe axis
+    # (switchsim.fabric): the group stays ONE program whose shards may
+    # each hold pipes from different scenario points — the per-scenario
+    # regrouping in run_matrix gathers across shard boundaries
+    # transparently (DESIGN.md §12).
+    return (cfg, chain, stacked), dict(
+        window=window, explicit_drops=explicit_drops, backend=backend,
+        faults=stacked_faults, devices=devices)
+
+
+def engine_programs(specs) -> list[tuple]:
+    """The engine programs ``run_matrix(specs)`` runs, one per compile
+    group, each as ``engine.pipes_program`` gives it: ``(fn, args)``,
+    whose ``fn.lower(*args).compile().as_text()`` names the stage of
+    every device op (DESIGN.md §14)."""
+    prepared, groups = _grouped(specs)
+    return [E.pipes_program(*args, **kw) for args, kw in
+            (_group_args(key, members, prepared)
+             for key, members in groups.items())]
+
+
 def run_matrix(specs, time_runs: bool = False,
                time_repeats: int = 1) -> list[ScenarioResult]:
     """Execute scenario points, batching trace-compatible ones.
@@ -125,36 +175,14 @@ def run_matrix(specs, time_runs: bool = False,
     engine-vs-loop speedup bench times the engine directly where exact
     per-run numbers matter).
     """
-    prepared = [_prepare(s) for s in specs]
-    groups: dict = {}
-    for i, p in enumerate(prepared):
-        steps = jax.tree.leaves(p.traces)[0].shape[1]
-        key = compile_key(p.spec, p.chain, steps)
-        groups.setdefault(key, []).append(i)
-
+    # host phases as disjoint profiler spans (DESIGN.md §14); run_pipes
+    # opens its own dispatch and finalize spans between these
+    prepared, groups = _grouped(specs)
     results: list = [None] * len(prepared)
     for key, members in groups.items():
-        (cfg, chain, window, _chunk, _steps, _pmax, explicit_drops,
-         _lane, backend, devices) = key
-        stacked = _cat_pipe_axis([prepared[i].traces for i in members])
-        # fault masks ride the same stacked pipe axis as the traces —
-        # healthy members contribute all-True columns, so one compiled
-        # program serves faulted and healthy points alike (DESIGN.md §10)
-        stacked_faults = F.concat([prepared[i].faults for i in members])
-
-        # ``devices`` shards the group's *concatenated* pipe axis
-        # (switchsim.fabric): the group stays ONE program whose shards
-        # may each hold pipes from different scenario points — the
-        # per-scenario regrouping below gathers across shard boundaries
-        # transparently (DESIGN.md §12).
-        def run(cfg=cfg, chain=chain, stacked=stacked, window=window,
-                explicit_drops=explicit_drops, backend=backend,
-                stacked_faults=stacked_faults, devices=devices):
-            return E.run_pipes(cfg, chain, stacked, window=window,
-                               explicit_drops=explicit_drops,
-                               backend=backend, faults=stacked_faults,
-                               devices=devices)
-
+        args, kw = _group_args(key, members, prepared)
+        chain, backend = args[1], kw["backend"]
+        run = functools.partial(E.run_pipes, *args, **kw)
         res = run()
         if time_runs:
             jax.block_until_ready(res.merged.payload)
@@ -165,40 +193,43 @@ def run_matrix(specs, time_runs: bool = False,
             group_wall = (time.perf_counter() - t0) / max(time_repeats, 1)
         else:
             group_wall = 0.0
-        offset = 0
-        for i in members:
-            p = prepared[i]
-            lo, hi = offset, offset + p.n_pipes
-            offset = hi
-            per_ctr = res.per_pipe_counters[lo:hi]
-            per_tel = res.per_pipe_telemetry[lo:hi]
-            per_nf = res.per_pipe_nf_counters[lo:hi]
-            tel = sum_telemetry(per_tel)
-            agg = {name: sum(c[name] for c in per_ctr) for name in C.NAMES}
-            nf_agg = {name: sum(c[name] for c in per_nf)
-                      for name in (per_nf[0] if per_nf else {})}
-            results[i] = ScenarioResult(
-                spec=p.spec,
-                counters=agg,
-                telemetry=tel,
-                per_pipe_counters=per_ctr,
-                per_pipe_telemetry=per_tel,
-                per_pipe_peak_occupancy=res.per_pipe_peak_occupancy[lo:hi],
-                nf_counters=nf_agg,
-                per_pipe_nf_counters=per_nf,
-                per_pipe_occ_series=res.per_pipe_occ_series[lo:hi],
-                gain=E.goodput_gain_from_telemetry(tel),
-                steer_stats=p.steer_stats,
-                nf_cycles=chain.cycle_costs(backend=backend),
-                wall_s=group_wall / len(members),
-                group_size=len(members),
-                group_wall_s=group_wall,
-                prepared=p,
-                merged=(res.merged if len(members) == 1 else
-                        jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi],
-                                     res.merged)),
-            )
-        assert offset == len(res.per_pipe_counters)
+        with jax.profiler.TraceAnnotation("repro.nf_cycles"):
+            nf_cycles = chain.cycle_costs(backend=backend)
+        with jax.profiler.TraceAnnotation("repro.finalize"):
+            offset = 0
+            for i in members:
+                p = prepared[i]
+                lo, hi = offset, offset + p.n_pipes
+                offset = hi
+                per_ctr = res.per_pipe_counters[lo:hi]
+                per_tel = res.per_pipe_telemetry[lo:hi]
+                per_nf = res.per_pipe_nf_counters[lo:hi]
+                tel = sum_telemetry(per_tel)
+                agg = {name: sum(c[name] for c in per_ctr) for name in C.NAMES}
+                nf_agg = {name: sum(c[name] for c in per_nf)
+                          for name in (per_nf[0] if per_nf else {})}
+                results[i] = ScenarioResult(
+                    spec=p.spec,
+                    counters=agg,
+                    telemetry=tel,
+                    per_pipe_counters=per_ctr,
+                    per_pipe_telemetry=per_tel,
+                    per_pipe_peak_occupancy=res.per_pipe_peak_occupancy[lo:hi],
+                    nf_counters=nf_agg,
+                    per_pipe_nf_counters=per_nf,
+                    per_pipe_occ_series=res.per_pipe_occ_series[lo:hi],
+                    gain=E.goodput_gain_from_telemetry(tel),
+                    steer_stats=p.steer_stats,
+                    nf_cycles=nf_cycles,
+                    wall_s=group_wall / len(members),
+                    group_size=len(members),
+                    group_wall_s=group_wall,
+                    prepared=p,
+                    merged=(res.merged if len(members) == 1 else
+                            jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi],
+                                         res.merged)),
+                )
+            assert offset == len(res.per_pipe_counters)
     return results
 
 

@@ -66,6 +66,7 @@ from repro.traffic import stream as stream_mod
 
 __all__ = [
     "EngineResult", "PipesResult", "run_engine", "run_pipes",
+    "pipes_program",
     "goodput_gain", "goodput_gain_from_telemetry", "recirc_slots",
     "recirc_select", "scan_step", "init_carry",
 ]
@@ -172,72 +173,84 @@ def scan_step(cfg: ParkConfig, chain: Chain, window: int,
     def step(carry, xs, drain):
         state, cstates, ring, lane, t = carry
         cin, s_up, l_up = xs
-        wire_b = _alive_bytes(cin)
-        wire_p = _alive_pkts(cin)
+        with jax.named_scope("engine.tally"):
+            wire_b = _alive_bytes(cin)
+            wire_p = _alive_pkts(cin)
         if recirc:
-            # Second pass for packets re-injected at the previous step
-            # (their wire bytes were paid on first arrival).
-            state, rout = recirc_fn(cfg, state, lane, backend=backend)
-        state, out = split_fn(cfg, state, cin, backend=backend)
+            with jax.named_scope("engine.lane"):
+                # Second pass for packets re-injected at the previous step
+                # (their wire bytes were paid on first arrival).
+                state, rout = recirc_fn(cfg, state, lane, backend=backend)
+        with jax.named_scope("engine.split"):
+            state, out = split_fn(cfg, state, cin, backend=backend)
         if recirc:
-            out, lane, n_denied = recirc_select(cfg, out, recirc)
-            state = dataclasses.replace(
-                state, counters=C.bump(state.counters,
-                                       "recirc_budget_drops", n_denied))
-            # recirculation-port traffic = what enters the lane this step
-            rec_b, rec_p = _alive_bytes(lane), _alive_pkts(lane)
-            nf_in = _cat_rows(rout, out)
+            with jax.named_scope("engine.lane"):
+                out, lane, n_denied = recirc_select(cfg, out, recirc)
+                state = dataclasses.replace(
+                    state, counters=C.bump(state.counters,
+                                           "recirc_budget_drops", n_denied))
+                nf_in = _cat_rows(rout, out)
+            with jax.named_scope("engine.tally"):
+                # recirculation-port traffic = what enters the lane this step
+                rec_b, rec_p = _alive_bytes(lane), _alive_pkts(lane)
         else:
-            rec_b = rec_p = jnp.zeros((), jnp.int32)
+            with jax.named_scope("engine.tally"):
+                rec_b = rec_p = jnp.zeros((), jnp.int32)
             nf_in = out
-        # to_server telemetry is tallied on nf_in BEFORE the kill: the
-        # switch still transmits to a dead server (the link is up, the
-        # host is not), so the forward link carries the bytes either way
-        to_srv_p, to_srv_b = _alive_pkts(nf_in), _alive_bytes(nf_in)
-        # Server fault (DESIGN.md §10): packets forwarded while this
-        # pipe's server is down are lost at send time.  The chain still
-        # runs on the step (dead rows are no-ops on NF state — a down
-        # server processes nothing).
-        killed = nf_in.alive & ~s_up
-        state = dataclasses.replace(
-            state, counters=C.bump(state.counters, "fault_drops",
-                                   jnp.sum(killed)))
-        srv_in = nf_in.replace(alive=nf_in.alive & s_up)
-        cstates, nf_out, dropped, _cycles = chain.run(
-            cstates, srv_in, backend=backend, ctx={"lb_up": l_up})
-        if explicit_drops:
-            nf_out = to_explicit_drops(nf_out, dropped)
-        # Drain-vs-drop rule: with drain, the failover agent turns each
-        # killed packet's parked payload into an OP=drop notification on
-        # the return path (the §6.2.4 machinery frees the slot at
-        # Merge); without it the slots leak until expiry-based eviction.
-        nf_out = to_explicit_drops(nf_out, killed & drain)
-        if window == 0:
-            returning = nf_out
-        else:
-            slot = jnp.mod(t, window)
-            returning = jax.tree.map(
-                lambda r: jax.lax.dynamic_index_in_dim(
-                    r, slot, axis=0, keepdims=False), ring)
-            ring = jax.tree.map(
-                lambda r, v: jax.lax.dynamic_update_index_in_dim(
-                    r, v, slot, axis=0), ring, nf_out)
-        state, m = merge_fn(cfg, state, returning, backend=backend)
-        # Per-link telemetry ys, keyed by LinkTelemetry field names
-        # (DESIGN.md §7); summed host-side in int64 by _finalize.
-        ys = dict(
-            merged=m, occ=occupancy(state),
-            wire_pkts=wire_p, wire_bytes=wire_b,
-            to_server_pkts=to_srv_p,
-            to_server_bytes=to_srv_b,
-            from_server_pkts=_alive_pkts(returning),
-            from_server_bytes=_alive_bytes(returning),
-            recirc_pkts=rec_p, recirc_bytes=rec_b,
-            merged_pkts=_alive_pkts(m), merged_bytes=_alive_bytes(m),
-        )
+        with jax.named_scope("engine.tally"):
+            # to_server telemetry is tallied on nf_in BEFORE the kill: the
+            # switch still transmits to a dead server (the link is up, the
+            # host is not), so the forward link carries the bytes either way
+            to_srv_p, to_srv_b = _alive_pkts(nf_in), _alive_bytes(nf_in)
+        with jax.named_scope("engine.nf"):
+            # Server fault (DESIGN.md §10): packets forwarded while this
+            # pipe's server is down are lost at send time.  The chain still
+            # runs on the step (dead rows are no-ops on NF state — a down
+            # server processes nothing).
+            killed = nf_in.alive & ~s_up
+            state = dataclasses.replace(
+                state, counters=C.bump(state.counters, "fault_drops",
+                                       jnp.sum(killed)))
+            srv_in = nf_in.replace(alive=nf_in.alive & s_up)
+            cstates, nf_out, dropped, _cycles = chain.run(
+                cstates, srv_in, backend=backend, ctx={"lb_up": l_up})
+            if explicit_drops:
+                nf_out = to_explicit_drops(nf_out, dropped)
+            # Drain-vs-drop rule: with drain, the failover agent turns each
+            # killed packet's parked payload into an OP=drop notification
+            # on the return path (the §6.2.4 machinery frees the slot at
+            # Merge); without it the slots leak until expiry-based eviction.
+            nf_out = to_explicit_drops(nf_out, killed & drain)
+        with jax.named_scope("engine.ring"):
+            if window == 0:
+                returning = nf_out
+            else:
+                slot = jnp.mod(t, window)
+                returning = jax.tree.map(
+                    lambda r: jax.lax.dynamic_index_in_dim(
+                        r, slot, axis=0, keepdims=False), ring)
+                ring = jax.tree.map(
+                    lambda r, v: jax.lax.dynamic_update_index_in_dim(
+                        r, v, slot, axis=0), ring, nf_out)
+            t = t + 1
+        with jax.named_scope("engine.merge"):
+            state, m = merge_fn(cfg, state, returning, backend=backend)
+        with jax.named_scope("engine.tally"):
+            # Per-link telemetry ys, keyed by LinkTelemetry field names
+            # (DESIGN.md §7); summed host-side in int64 by _finalize.
+            ys = dict(
+                merged=m, occ=occupancy(state),
+                wire_pkts=wire_p, wire_bytes=wire_b,
+                to_server_pkts=to_srv_p,
+                to_server_bytes=to_srv_b,
+                from_server_pkts=_alive_pkts(returning),
+                from_server_bytes=_alive_bytes(returning),
+                recirc_pkts=rec_p, recirc_bytes=rec_b,
+                merged_pkts=_alive_pkts(m), merged_bytes=_alive_bytes(m),
+            )
         if collect_sent:
             ys["sent"] = nf_in
-        return (state, cstates, ring, lane, t + 1), ys
+        return (state, cstates, ring, lane, t), ys
 
     return step
 
@@ -412,6 +425,41 @@ def _as_pipe_traces(traces) -> PacketBatch:
         f"TraceSources; got {type(traces).__name__}")
 
 
+def pipes_program(
+    cfg: ParkConfig,
+    chain: Chain,
+    traces,
+    window: int = 1,
+    explicit_drops: bool = False,
+    backend=None,
+    collect_sent: bool = False,
+    faults=None,
+    devices: int = 1,
+):
+    """The compiled engine program ``run_pipes`` runs on these arguments,
+    and what it is called with: ``(fn, args)``, the traces and fault
+    masks padded over the drain steps.  ``fn(*args)`` is the engine call;
+    ``fn.lower(*args).compile().as_text()`` is its program, whose op
+    names carry the stage scopes a device trace is read by (DESIGN.md
+    §14)."""
+    backend = coerce_backend(backend)
+    traces = _as_pipe_traces(traces)
+    n_pipes = jax.tree.leaves(traces)[0].shape[0]
+    chunk = jax.tree.leaves(traces)[0].shape[2]
+    steps = jax.tree.leaves(traces)[0].shape[1]
+    lane = recirc_slots(cfg, chunk)
+    pad = window + (1 if lane else 0)
+    fa = F.resolve(faults, pipes=n_pipes, steps=steps)
+    s_up, l_up, drain = _pad_masks(fa, pad)
+    traces = _pad_trace(traces, pad, axis=1)
+    if devices != 1:
+        from repro.switchsim import fabric
+        devices = fabric.resolve_devices(n_pipes, devices)
+    fn = _compiled(cfg, chain, window, explicit_drops, backend,
+                   collect_sent, pipes=True, recirc=lane, devices=devices)
+    return fn, (traces, s_up, l_up, drain)
+
+
 def run_pipes(
     cfg: ParkConfig,
     chain: Chain,
@@ -443,50 +491,44 @@ def run_pipes(
     request falls back to 1 with a warning when the pipe count does not
     divide it, and raises when fewer devices are visible.
     """
-    backend = coerce_backend(backend)
-    traces = _as_pipe_traces(traces)
-    n_pipes = jax.tree.leaves(traces)[0].shape[0]
-    chunk = jax.tree.leaves(traces)[0].shape[2]
-    steps = jax.tree.leaves(traces)[0].shape[1]
-    lane = recirc_slots(cfg, chunk)
-    pad = window + (1 if lane else 0)
-    fa = F.resolve(faults, pipes=n_pipes, steps=steps)
-    s_up, l_up, drain = _pad_masks(fa, pad)
-    traces = _pad_trace(traces, pad, axis=1)
-    if devices != 1:
-        from repro.switchsim import fabric
-        devices = fabric.resolve_devices(n_pipes, devices)
-    fn = _compiled(cfg, chain, window, explicit_drops, backend,
-                   collect_sent, pipes=True, recirc=lane, devices=devices)
-    state, cstates, ys = fn(traces, s_up, l_up, drain)
-    merged, sent, occ = _finalize(ys, window, collect_sent, time_axis=1)
-    per_tel = _per_pipe_telemetry(ys)
-    tel = sum_telemetry(per_tel)
-    occ_pp = np.asarray(ys["occ"], np.int64)  # (P, T+pad)
-    per_occ = [int(v) for v in occ_pp.max(axis=-1)] if occ_pp.size \
-        else [0] * n_pipes
-    ctr = np.asarray(state.counters, np.int64)  # (P, C.NUM)
-    agg = dict(zip(C.NAMES, (int(v) for v in ctr.sum(axis=0))))
-    per_pipe = [dict(zip(C.NAMES, (int(v) for v in ctr[p])))
-                for p in range(n_pipes)]
-    per_nf = [_nf_counters(chain, jax.tree.map(lambda a: a[p], cstates))
-              for p in range(n_pipes)]
-    nf_agg = {k: sum(d[k] for d in per_nf)
-              for k in (per_nf[0] if per_nf else {})}
-    return PipesResult(
-        merged=merged, sent=sent, state=state,
-        counters=agg, srv_bytes=tel.srv_bytes,
-        srv_fwd_bytes=tel.to_server_bytes, wire_bytes=tel.wire_bytes,
-        ret_bytes=tel.merged_bytes, peak_occupancy=occ, telemetry=tel,
-        occ_series=occ_pp, nf_counters=nf_agg,
-        per_pipe_counters=per_pipe,
-        per_pipe_srv_bytes=[t.srv_bytes for t in per_tel],
-        per_pipe_wire_bytes=[t.wire_bytes for t in per_tel],
-        per_pipe_telemetry=per_tel,
-        per_pipe_peak_occupancy=per_occ,
-        per_pipe_occ_series=occ_pp,
-        per_pipe_nf_counters=per_nf,
-    )
+    # host phases as disjoint profiler spans (DESIGN.md §14): everything
+    # up to the enqueue of the engine, then the gathering of results
+    with jax.profiler.TraceAnnotation("repro.dispatch"):
+        fn, args = pipes_program(cfg, chain, traces, window=window,
+                                 explicit_drops=explicit_drops,
+                                 backend=backend, collect_sent=collect_sent,
+                                 faults=faults, devices=devices)
+        state, cstates, ys = fn(*args)
+    with jax.profiler.TraceAnnotation("repro.finalize"):
+        n_pipes = jax.tree.leaves(args[0])[0].shape[0]
+        merged, sent, occ = _finalize(ys, window, collect_sent, time_axis=1)
+        per_tel = _per_pipe_telemetry(ys)
+        tel = sum_telemetry(per_tel)
+        occ_pp = np.asarray(ys["occ"], np.int64)  # (P, T+pad)
+        per_occ = [int(v) for v in occ_pp.max(axis=-1)] if occ_pp.size \
+            else [0] * n_pipes
+        ctr = np.asarray(state.counters, np.int64)  # (P, C.NUM)
+        agg = dict(zip(C.NAMES, (int(v) for v in ctr.sum(axis=0))))
+        per_pipe = [dict(zip(C.NAMES, (int(v) for v in ctr[p])))
+                    for p in range(n_pipes)]
+        per_nf = [_nf_counters(chain, jax.tree.map(lambda a: a[p], cstates))
+                  for p in range(n_pipes)]
+        nf_agg = {k: sum(d[k] for d in per_nf)
+                  for k in (per_nf[0] if per_nf else {})}
+        return PipesResult(
+            merged=merged, sent=sent, state=state,
+            counters=agg, srv_bytes=tel.srv_bytes,
+            srv_fwd_bytes=tel.to_server_bytes, wire_bytes=tel.wire_bytes,
+            ret_bytes=tel.merged_bytes, peak_occupancy=occ, telemetry=tel,
+            occ_series=occ_pp, nf_counters=nf_agg,
+            per_pipe_counters=per_pipe,
+            per_pipe_srv_bytes=[t.srv_bytes for t in per_tel],
+            per_pipe_wire_bytes=[t.wire_bytes for t in per_tel],
+            per_pipe_telemetry=per_tel,
+            per_pipe_peak_occupancy=per_occ,
+            per_pipe_occ_series=occ_pp,
+            per_pipe_nf_counters=per_nf,
+        )
 
 
 def goodput_gain(res: EngineResult) -> dict[str, Any]:

@@ -66,7 +66,8 @@ from repro.switchsim.telemetry import TEL_FIELDS, LinkTelemetry
 from repro.traffic.stream import (MaterializedSource, SyntheticSource,
                                   TraceSource, as_source, splitmix32)
 
-__all__ = ["run_stream", "replay_oracle", "StreamOracleMismatch",
+__all__ = ["run_stream", "segment_program", "replay_oracle",
+           "StreamOracleMismatch",
            "sojourn_ns", "step_ns_for", "SPLIT_MERGE_NS"]
 
 # Paper §4: the split->merge dwell a parked payload spends in the switch is
@@ -140,11 +141,13 @@ def _segment_program(cfg: ParkConfig, chain: Chain, window: int,
         def body(c, xs):
             core, vals, n = c
             core, ys = step(core, xs, drain)
-            m = ys["merged"]
-            lane_rows = jnp.arange(m.alive.shape[0]) < recirc
-            sample = sojourn_ns(m.pkt_len(), lane_rows, window, step_ns)
-            vals, n = _reservoir_insert(vals, n, sample, m.alive, res_seed)
-            tel = jnp.stack([ys[f] for f in TEL_FIELDS])
+            with jax.named_scope("engine.tally"):
+                m = ys["merged"]
+                lane_rows = jnp.arange(m.alive.shape[0]) < recirc
+                sample = sojourn_ns(m.pkt_len(), lane_rows, window, step_ns)
+                vals, n = _reservoir_insert(vals, n, sample, m.alive,
+                                            res_seed)
+                tel = jnp.stack([ys[f] for f in TEL_FIELDS])
             return (core, vals, n), (tel, ys["occ"])
 
         (core, vals, n), (tels, occ) = jax.lax.scan(
@@ -173,6 +176,71 @@ def _quantiles_us(vals: np.ndarray, n: int) -> dict:
                         ("p999_us", 0.999)):
             out[name] = float(np.quantile(valid, q, method="nearest")) / 1e3
     return out
+
+
+def _stream_setup(cfg: ParkConfig, chain: Chain, source, window: int,
+                  segment_len: int, explicit_drops: bool, backend,
+                  reservoir: int, reservoir_seed: int):
+    """``run_stream``'s checks and set-up: the source, the segment
+    program, its first carry, the drain flag, one chunk's shape and the
+    drain pad's length."""
+    backend = coerce_backend(backend)
+    source = as_source(source)
+    if source.steps < 1:
+        raise ValueError("streaming needs a source with >= 1 step")
+    if segment_len < 1:
+        raise ValueError(f"segment_len must be >= 1, got {segment_len}")
+    if reservoir < 1:
+        raise ValueError(f"reservoir must be >= 1, got {reservoir}")
+    chunk = source.chunk
+    # Per-segment telemetry sums are int32 on device: bound the worst-case
+    # byte sum (every row alive at max frame size) under 2^31.
+    frame = source.pmax + 64
+    if segment_len * chunk * frame >= 2**31:
+        raise ValueError(
+            f"segment_len {segment_len} overflows int32 telemetry "
+            f"(chunk={chunk}, pmax={source.pmax}); use shorter segments")
+    lane = recirc_slots(cfg, chunk)
+    pad = window + (1 if lane else 0)
+    step_ns = step_ns_for(window)
+    fn = _segment_program(cfg, chain, window, explicit_drops, backend,
+                          lane, step_ns, reservoir_seed)
+    chunk_like = jax.tree.map(lambda a: a[0], source.segment(0, 1))
+    carry = (init_carry(cfg, chain, chunk_like, window, lane),
+             jnp.zeros((reservoir,), jnp.int32),
+             jnp.zeros((), jnp.int32))
+    drain = jnp.asarray(False)
+    return source, fn, carry, drain, chunk_like, pad
+
+
+def _segment_args(carry, seg, drain) -> tuple:
+    """The segment program's arguments for one segment: the carry, the
+    segment's chunks and healthy fault masks over its steps."""
+    ones = jnp.ones((jax.tree.leaves(seg)[0].shape[0],), bool)
+    return carry, seg, ones, ones, drain
+
+
+def segment_program(
+    cfg: ParkConfig,
+    chain: Chain,
+    source,
+    window: int = 1,
+    segment_len: int = 256,
+    explicit_drops: bool = False,
+    backend=None,
+    reservoir: int = 4096,
+    reservoir_seed: int = 0x5EED,
+):
+    """The segment program ``run_stream`` runs on these arguments, and
+    its call on the first segment: ``(fn, args)``.
+    ``fn.lower(*args).compile().as_text()`` is the program, whose op
+    names carry the stage scopes a device trace is read by (DESIGN.md
+    §14)."""
+    source, fn, carry, drain, _, _ = _stream_setup(
+        cfg, chain, source, window, segment_len, explicit_drops, backend,
+        reservoir, reservoir_seed)
+    seg = source.segment(0, min(segment_len, source.steps))
+    return fn, _segment_args(carry, seg, drain)
 
 
 def run_stream(
@@ -206,32 +274,9 @@ def run_stream(
     Faults are not supported here (healthy masks only); use the
     materialized entry points for fault studies.
     """
-    backend = coerce_backend(backend)
-    source = as_source(source)
-    if source.steps < 1:
-        raise ValueError("streaming needs a source with >= 1 step")
-    if segment_len < 1:
-        raise ValueError(f"segment_len must be >= 1, got {segment_len}")
-    if reservoir < 1:
-        raise ValueError(f"reservoir must be >= 1, got {reservoir}")
-    chunk = source.chunk
-    # Per-segment telemetry sums are int32 on device: bound the worst-case
-    # byte sum (every row alive at max frame size) under 2^31.
-    frame = source.pmax + 64
-    if segment_len * chunk * frame >= 2**31:
-        raise ValueError(
-            f"segment_len {segment_len} overflows int32 telemetry "
-            f"(chunk={chunk}, pmax={source.pmax}); use shorter segments")
-    lane = recirc_slots(cfg, chunk)
-    pad = window + (1 if lane else 0)
-    step_ns = step_ns_for(window)
-    fn = _segment_program(cfg, chain, window, explicit_drops, backend,
-                          lane, step_ns, reservoir_seed)
-    chunk_like = jax.tree.map(lambda a: a[0], source.segment(0, 1))
-    carry = (init_carry(cfg, chain, chunk_like, window, lane),
-             jnp.zeros((reservoir,), jnp.int32),
-             jnp.zeros((), jnp.int32))
-    drain = jnp.asarray(False)
+    source, fn, carry, drain, chunk_like, pad = _stream_setup(
+        cfg, chain, source, window, segment_len, explicit_drops, backend,
+        reservoir, reservoir_seed)
     tel_total = np.zeros((len(TEL_FIELDS),), np.int64)
     occ_segments: list[dict] = []
     peak = 0
@@ -244,25 +289,34 @@ def run_stream(
         if jax.default_backend() == "cpu":
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
+        # host phases of each segment, as disjoint profiler spans
+        # (DESIGN.md §14): the source's draw, the enqueue, the sync
         for start in range(0, source.steps, segment_len):
             n = min(segment_len, source.steps - start)
-            ones = jnp.ones((n,), bool)
-            carry, tel, occ = fn(carry, source.segment(start, n),
-                                 ones, ones, drain)
-            tel_total += np.asarray(tel, np.int64)
-            occ = np.asarray(occ, np.int64)
-            occ_segments.append(_occ_summary(start, occ))
-            peak = max(peak, int(occ.max()))
+            with jax.profiler.TraceAnnotation("repro.stream.source"):
+                seg = source.segment(start, n)
+            with jax.profiler.TraceAnnotation("repro.stream.dispatch"):
+                carry, tel, occ = fn(*_segment_args(carry, seg, drain))
+                # one segment of packets is live at a time: the next is
+                # drawn only after this one is freed
+                del seg
+            with jax.profiler.TraceAnnotation("repro.stream.sync"):
+                tel_total += np.asarray(tel, np.int64)
+                occ = np.asarray(occ, np.int64)
+                occ_segments.append(_occ_summary(start, occ))
+                peak = max(peak, int(occ.max()))
             n_segments += 1
         if pad:
-            dead = jax.tree.map(
-                lambda a: jnp.zeros((pad,) + a.shape, a.dtype), chunk_like)
-            ones = jnp.ones((pad,), bool)
-            carry, tel, occ = fn(carry, dead, ones, ones, drain)
-            tel_total += np.asarray(tel, np.int64)
-            occ = np.asarray(occ, np.int64)
-            occ_segments.append(_occ_summary(source.steps, occ))
-            peak = max(peak, int(occ.max()))
+            with jax.profiler.TraceAnnotation("repro.stream.dispatch"):
+                dead = jax.tree.map(
+                    lambda a: jnp.zeros((pad,) + a.shape, a.dtype),
+                    chunk_like)
+                carry, tel, occ = fn(*_segment_args(carry, dead, drain))
+            with jax.profiler.TraceAnnotation("repro.stream.sync"):
+                tel_total += np.asarray(tel, np.int64)
+                occ = np.asarray(occ, np.int64)
+                occ_segments.append(_occ_summary(source.steps, occ))
+                peak = max(peak, int(occ.max()))
     (state, cstates, _, _, _), vals, n_samples = carry
     tel = LinkTelemetry(**{f: int(v)
                            for f, v in zip(TEL_FIELDS, tel_total)})
