@@ -133,6 +133,52 @@ def occupancy(state: ParkState) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
+# Per-row payload shift
+# --------------------------------------------------------------------------
+
+def _shift_rows(x: jax.Array, shift: jax.Array, bound: int,
+                left: bool) -> jax.Array:
+    """Shift each row of ``x`` by its own ``shift`` along the last axis.
+
+    ``x`` is ``(rows, width)`` and ``shift`` an int32 ``(rows,)`` in
+    ``[0, bound]``; vacated bytes read 0, and a shift at or beyond the
+    width gives an all-zero row.  ``bound.bit_length()`` stages: stage k
+    moves the rows whose bit k is set by ``2**k``, a slice of the
+    zero-padded rows and a select, with no gather (a per-element
+    ``take_along_axis`` moves the same bytes at about 90 MB/s on a TPU
+    v5e, DESIGN.md §2).  The stages run as a loop, in int32: unrolled, the
+    stages' own code made each engine program on the chip over 1 MB
+    larger.
+    """
+    width = x.shape[1]
+    if bound >= width:
+        shift, bound = jnp.minimum(shift, width), width
+    stages = bound.bit_length()
+    pad = 1 << (stages - 1)  # the last stage's shift
+
+    def stage(k, y):
+        z = jnp.zeros((y.shape[0], pad), y.dtype)
+        if left:
+            moved = jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([y, z], axis=1), 1 << k, width, axis=1)
+        else:
+            moved = jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([z, y], axis=1), pad - (1 << k), width, axis=1)
+        return jnp.where(((shift >> k) & 1)[:, None] == 1, moved, y)
+
+    return jax.lax.fori_loop(0, stages, stage,
+                             x.astype(jnp.int32)).astype(x.dtype)
+
+
+def _row_prefix(x: jax.Array, width: int) -> jax.Array:
+    """The first ``width`` bytes of each row of ``x``, zero-padded where
+    ``x`` is narrower (pmax < park_bytes is legal; a parked row is then
+    partly unreachable)."""
+    head = x[:, :width]
+    return jnp.pad(head, ((0, 0), (0, width - head.shape[1])))
+
+
+# --------------------------------------------------------------------------
 # Split (paper Algorithm 1)
 # --------------------------------------------------------------------------
 
@@ -201,11 +247,8 @@ def split_fn(cfg: ParkConfig, state: ParkState, pkts: PacketBatch,
 
     # -- stage 3..N: stripe payload blocks into the payload table -----------
     # Claiming zeroes the full row (incl. lanes above pass_bytes), so a later
-    # recirculation pass appends into zeros.  pmax < park_bytes is legal (the
-    # row is then partly unreachable); pad the slice up to the row width.
-    park = pkts.payload[:, : cfg.park_bytes]
-    if park.shape[1] < cfg.park_bytes:
-        park = jnp.pad(park, ((0, 0), (0, cfg.park_bytes - park.shape[1])))
+    # recirculation pass appends into zeros.
+    park = _row_prefix(pkts.payload, cfg.park_bytes)
     lane = jnp.arange(cfg.park_bytes)[None, :]
     park = jnp.where(lane < d["park_len"][:, None], park, 0)
     ptable = dispatch("payload_store", backend)(
@@ -221,10 +264,7 @@ def split_fn(cfg: ParkConfig, state: ParkState, pkts: PacketBatch,
 
     # -- packet transformation: drop the parked prefix, add the PP header ---
     shift = d["park_len"]
-    idx = jnp.arange(cfg.pmax)[None, :] + shift[:, None]
-    remainder = jnp.take_along_axis(
-        pkts.payload, jnp.clip(idx, 0, cfg.pmax - 1), axis=1
-    )
+    remainder = _shift_rows(pkts.payload, shift, cfg.pass_bytes, left=True)
     new_len = pkts.payload_len - shift
     keep = jnp.arange(cfg.pmax)[None, :] < new_len[:, None]
     remainder = jnp.where(keep, remainder, 0)
@@ -297,19 +337,17 @@ def recirc_fn(cfg: ParkConfig, state: ParkState, pkts: PacketBatch,
         0)
     do_ext = own & (extra > 0)
 
+    ins = _shift_rows(_row_prefix(pkts.payload, cfg.park_bytes), cur,
+                      cfg.park_bytes, left=False)
     col = jnp.arange(cfg.park_bytes)[None, :]
     src = col - cur[:, None]
-    ins = jnp.take_along_axis(
-        pkts.payload, jnp.clip(src, 0, cfg.pmax - 1), axis=1)
     region = (src >= 0) & (src < extra[:, None])
     new_row = jnp.where(region, ins, state.ptable[ti])
     rows = jnp.where(do_ext, ti, cfg.capacity)  # OOB rows dropped
     ptable = state.ptable.at[rows].set(new_row, mode="drop")
     meta_len = state.meta_len.at[rows].set(cur + extra, mode="drop")
 
-    idx = jnp.arange(cfg.pmax)[None, :] + extra[:, None]
-    remainder = jnp.take_along_axis(
-        pkts.payload, jnp.clip(idx, 0, cfg.pmax - 1), axis=1)
+    remainder = _shift_rows(pkts.payload, extra, cfg.park_bytes, left=True)
     new_len = pkts.payload_len - extra
     keep = jnp.arange(cfg.pmax)[None, :] < new_len[:, None]
     remainder = jnp.where(keep, remainder, 0)
@@ -415,14 +453,10 @@ def merge_fn(cfg: ParkConfig, state: ParkState, pkts: PacketBatch,
     # -- packet transformation: payload := parked ++ carried remainder ------
     shift = jnp.where(fetch, d["park_len"], 0)
     col = jnp.arange(cfg.pmax)[None, :]
-    rem_idx = col - shift[:, None]
-    carried = jnp.take_along_axis(
-        pkts.payload, jnp.clip(rem_idx, 0, cfg.pmax - 1), axis=1)
+    carried = _shift_rows(pkts.payload, shift, cfg.park_bytes, left=False)
     # Clamp for pmax < park_bytes (parked length never exceeds the payload
     # that fit in pmax, so truncating the row loses nothing).
-    pad = jnp.zeros((pkts.batch_size, max(cfg.pmax - cfg.park_bytes, 0)),
-                    jnp.uint8)
-    parked_full = jnp.concatenate([parked, pad], axis=1)[:, : cfg.pmax]
+    parked_full = _row_prefix(parked, cfg.pmax)
     new_payload = jnp.where(col < shift[:, None], parked_full, carried)
     new_len = pkts.payload_len + shift
     keep = col < new_len[:, None]

@@ -1,6 +1,9 @@
 """Core PayloadPark: unit tests + hypothesis property tests (paper Alg. 1/2)."""
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 pytest.importorskip(
@@ -13,7 +16,8 @@ from repro.core.header import crc16_tag
 from repro.core.packet import (HDR_BYTES, OP_DROP, PP_HDR_BYTES,
                                make_udp_batch, wire_bytes)
 from repro.core.park import (PARK_BYTES_BASE, PARK_BYTES_RECIRC, ParkConfig,
-                             init_state, merge, occupancy, recirc, split)
+                             _shift_rows, init_state, merge, merge_fn,
+                             occupancy, recirc, recirc_fn, split, split_fn)
 
 CFG = ParkConfig(capacity=64, max_exp=2, pmax=1024)
 
@@ -228,3 +232,84 @@ def test_backend_paths_match():
     st_b2, out_b = merge(CFG, st_b, sent_b, backend="pallas_interpret")
     assert jnp.all(out_a.payload == out_b.payload)
     assert jnp.all(st_a2.ptable == st_b2.ptable)
+
+
+# --------------------------------------------------------------------------
+# Per-row payload shift (the log-step shifter behind Split, Merge, recirc)
+# --------------------------------------------------------------------------
+
+def _gather_shift(x, shift, left):
+    """The formulation the shifter replaced: a clipped per-element gather."""
+    col = jnp.arange(x.shape[1])[None, :]
+    idx = col + shift[:, None] if left else col - shift[:, None]
+    return jnp.take_along_axis(x, jnp.clip(idx, 0, x.shape[1] - 1), axis=1)
+
+
+def _caller_mask(width, shift, length, left):
+    """The bytes a caller keeps of the shifted rows: Split's and recirc's
+    ``keep`` (left), Merge's ``col >= shift`` and recirc's ``region``
+    (right), each over a row of ``length`` live bytes."""
+    col = jnp.arange(width)[None, :]
+    if left:
+        return col < (length - shift)[:, None]
+    return (col >= shift[:, None]) & (col < (length + shift)[:, None])
+
+
+# (width, bound): Split's pmax-wide rows shifted by up to pass_bytes, the
+# lane's and Merge's by up to park_bytes, and a pmax below park_bytes.
+_GEOMETRY = {"split": (512, PARK_BYTES_BASE), "merge": (512, PARK_BYTES_RECIRC),
+             "pmax_below_park": (128, PARK_BYTES_RECIRC)}
+
+
+@pytest.mark.parametrize("pattern", ["zero", "one", "bound", "random", "dead"])
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRY))
+@pytest.mark.parametrize("left", [True, False], ids=["left", "right"])
+def test_shift_rows_matches_clipped_gather(left, geometry, pattern):
+    """On every byte the callers' masks keep, the shifter equals the clipped
+    ``take_along_axis``; the bytes it vacates read 0."""
+    width, bound = _GEOMETRY[geometry]
+    rows = 24
+    rng = np.random.default_rng([int(left), width, bound, len(pattern)])
+    length = rng.integers(0, width + 1, rows).astype(np.int32)
+    x = rng.integers(0, 256, (rows, width)).astype(np.uint8)
+    shift = {"zero": np.zeros(rows), "one": np.ones(rows),
+             "bound": np.full(rows, bound),
+             "random": rng.integers(0, bound + 1, rows),
+             "dead": rng.integers(0, bound + 1, rows)}[pattern]
+    alive = (np.zeros(rows, bool) if pattern == "dead"
+             else rng.random(rows) < 0.8 if pattern == "random"
+             else np.ones(rows, bool))
+    x, length, alive = jnp.asarray(x), jnp.asarray(length), jnp.asarray(alive)
+    shift = jnp.where(alive, jnp.asarray(shift, jnp.int32), 0)  # dead: 0
+
+    got = _shift_rows(x, shift, bound, left)
+    assert got.dtype == jnp.uint8 and got.shape == x.shape
+    keep = _caller_mask(width, shift, length, left)
+
+    def caller(shifted):
+        return jnp.where(alive[:, None], jnp.where(keep, shifted, 0), x)
+
+    np.testing.assert_array_equal(caller(got),
+                                  caller(_gather_shift(x, shift, left)))
+    col = jnp.arange(width)[None, :]
+    vacated = (col >= width - shift[:, None]) if left else (
+        col < shift[:, None])
+    assert not bool(jnp.any(jnp.where(vacated, got, 0)))
+
+
+@pytest.mark.parametrize("recirculation", [False, True], ids=["off", "on"])
+@pytest.mark.parametrize("fn", [split_fn, merge_fn, recirc_fn],
+                         ids=["split", "merge", "recirc"])
+def test_no_per_element_payload_gather(fn, recirculation):
+    """Split, Merge and the recirculation pass move payload bytes without a
+    gather over the payload buffer, as uint8 or widened (a per-element
+    gather there runs at ~90 MB/s on a TPU v5e, DESIGN.md §2)."""
+    pmax, batch = 384, 12  # shapes no other operand of these functions has
+    cfg = ParkConfig(capacity=32, max_exp=2, pmax=pmax,
+                     recirculation=recirculation)
+    pkts = make_udp_batch(jax.random.key(0), batch, 300, pmax=pmax)
+    text = jax.jit(fn, static_argnums=0).lower(
+        cfg, init_state(cfg), pkts).as_text()
+    payload_gather = re.compile(
+        rf"gather.*\(tensor<{batch}x{pmax}x\w+>")
+    assert not [ln for ln in text.splitlines() if payload_gather.search(ln)]
