@@ -17,9 +17,11 @@ A driver is chosen by the configuration's ``engine`` key:
 ``warm_up`` compiles every program the window's calls run, on call 0's
 traffic, which no timed call repeats.
 
-Every call keeps what the check needs on the host: counters, telemetry,
+A call ends when every array it returned is ready on the device.  Then
+it keeps what the check needs on the host: counters, telemetry,
 occupancy, and for a sample of pipes drawn from the seed the merged
-packets themselves.  After the window, ``check`` replays those calls
+packets themselves.  Those copies are the benchmark's own work and lie
+outside the call's time.  After the window, ``check`` replays those calls
 through the numpy reference and counts every fact on which the two
 disagree.
 """
@@ -36,11 +38,13 @@ from repro.nf.chain import Chain
 from repro.nf.nat import Nat
 from repro.scenarios import ScenarioSpec, run_matrix
 from repro.scenarios.spec import build_chain, resolve_workload
+from repro.switchsim import fabric
 from repro.switchsim.stream import run_stream
 from repro.traffic.stream import DiurnalLoad, SyntheticSource, TraceSource
 
 from bench import generator as G
 from bench import reference as R
+from bench.harness import CellError
 
 MAX_SEED = 2**31 - 16       # the simulator's seeds must stay within int32
 
@@ -171,6 +175,7 @@ class MatrixDriver:
         self.config, self.traffic, self.seed = config, traffic, seed
         park = config["park"]
         self.pipes = config["pipes"]
+        self.devices = config.get("devices", 1)
         self.packets = self.pipes * config["packets_per_pipe"]
         self.spec_kw = dict(
             name=config["name"], workload=tuple(traffic["workload"]),
@@ -181,8 +186,9 @@ class MatrixDriver:
             window=config["window"], pmax=park["pmax"],
             flows=config["flows"], fw_rules=config["fw_rules"],
             nat_capacity=config["nat"]["capacity"],
-            backend=config["backend"])
-        self.check_pipes = min(check_pipes, self.pipes)
+            backend=config["backend"], devices=self.devices)
+        # one pipe of each shard at least, so no chip goes unchecked
+        self.check_pipes = min(max(check_pipes, self.devices), self.pipes)
         self.kept: list[dict] = []
         lane = (math.floor(park["recirc_frac"] * config["chunk"] + 1e-9)
                 if park["recirculation"] else 0)
@@ -190,7 +196,10 @@ class MatrixDriver:
         self.drain = config["window"] + (1 if lane else 0)
 
     def warm_up(self) -> None:
-        self.call(0, keep=False)
+        """Call 0, kept as a timed call keeps its pipes (which compiles
+        the slicing of them), then forgotten."""
+        self.call(0, keep=True)
+        self.kept.clear()
 
     def spec(self, i: int):
         return ScenarioSpec(seed=derive_seed(self.seed, i), **self.spec_kw)
@@ -200,8 +209,29 @@ class MatrixDriver:
         configuration file's terms, to hold the file to it."""
         spec = self.spec(0)
         nfs = {type(nf).__name__: nf for nf in build_chain(spec, None).nfs}
+        try:
+            devices = fabric.resolve_devices(spec.pipes, spec.devices)
+        except ValueError as e:     # more devices asked than JAX sees
+            raise CellError(f"{spec.name}: {e}") from None
         return dict(park=spec.park_config(), nfs=nfs,
-                    workload=resolve_workload(spec.workload))
+                    workload=resolve_workload(spec.workload),
+                    devices=devices)
+
+    def sample(self, rng) -> list[int]:
+        """The pipes of one call that the check replays, drawn from
+        ``rng``: on one device ``check_pipes`` of them; sharded, a pipe
+        of each shard (the shards hold equal runs of pipes) and as many
+        more as make ``check_pipes``."""
+        if self.devices == 1:
+            pick = rng.choice(self.pipes, self.check_pipes, replace=False)
+        else:
+            per = self.pipes // self.devices
+            first = per * np.arange(self.devices) \
+                + rng.integers(per, size=self.devices)
+            rest = rng.choice(np.setdiff1d(np.arange(self.pipes), first),
+                              self.check_pipes - self.devices, replace=False)
+            pick = np.concatenate([first, rest])
+        return sorted(int(q) for q in pick)
 
     def call(self, i: int, keep: bool, tracer: Tracer | None = None) -> Call:
         """One whole call; with a ``tracer``, all of it is profiled."""
@@ -209,6 +239,7 @@ class MatrixDriver:
             tracer.start()
         t0 = time.perf_counter()
         res = run_matrix([self.spec(i)])[0]
+        jax.block_until_ready(res.merged)
         t1 = time.perf_counter()
         if tracer:
             tracer.stop()
@@ -225,9 +256,8 @@ class MatrixDriver:
         if tracer:
             call.traced_steps, call.traced_whole = call.pipe_steps, True
         if keep:
-            rng = np.random.default_rng([self.seed % 2**64, i])
-            sample = sorted(int(q) for q in rng.choice(
-                self.pipes, self.check_pipes, replace=False))
+            sample = self.sample(
+                np.random.default_rng([self.seed % 2**64, i]))
             self.kept.append(dict(
                 call=i, steer=dict(res.steer_stats), pipes={
                     q: dict(counters=dict(res.per_pipe_counters[q]),
@@ -252,7 +282,8 @@ class MatrixDriver:
             before = checks.total()
             pkts = G.call_packets(derive_seed(self.seed, kept["call"]),
                                   tr, self.packets, cfg["park"]["pmax"],
-                                  cfg["flows"], cfg["flow_pool_seed"])
+                                  cfg["flows"], cfg["flow_pool_seed"],
+                                  self.devices)
             traces, stats = G.steer(pkts, self.pipes, cfg["chunk"])
             checks.add("steering_diffs", _dict_diffs(kept["steer"], stats))
             for q, got in kept["pipes"].items():
@@ -366,7 +397,7 @@ class StreamDriver:
             self._build()
         return dict(park=self.park,
                     nfs={type(nf).__name__: nf for nf in self.chain.nfs},
-                    workload=self.workload)
+                    workload=self.workload, devices=1)
 
     def offset(self, i: int) -> int:
         """The timeline step at which call ``i`` starts (call 0 warms up)."""
@@ -403,6 +434,7 @@ class StreamDriver:
         src = SliceSource(self._source, self.offset(i), self.steps, hooks)
         t0 = time.perf_counter()
         res = self._run(src)
+        jax.block_until_ready(res.state)
         t1 = time.perf_counter()
         steps = self.steps + self.drain
         rows = cfg["chunk"] + self.lane

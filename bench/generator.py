@@ -5,17 +5,23 @@ points (``scenarios.run_matrix`` builds packets from the spec's seed,
 ``switchsim.stream.run_stream`` pulls them from a ``SyntheticSource``).
 The reference must not take those packets from the program, so this
 module draws the same packets again from the same seed: the same
-``jax.random`` calls, in the same order, on the same device, which give
-the same bits.  Everything after the random draws (flow identities,
-steering onto pipes) is plain numpy.
+``jax.random`` calls, in the same order, which give the same bits.  A
+deployment sharded over several chips is drawn by those calls jitted,
+their outputs sharded over the chips along the packet axis, so that no
+chip holds the whole batch; JAX's partitionable threefry gives a
+sharded draw the bits of the unsharded one.  Everything after the random
+draws (flow identities, steering onto pipes) is plain numpy.
 
 A packet batch here is a dict of numpy arrays keyed by ``FIELDS``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 HDR_BYTES = 42
 FIELDS = ("dst_mac", "src_mac", "src_ip", "dst_ip", "proto", "src_port",
@@ -79,16 +85,41 @@ def flow_pool(n_flows: int, seed: int):
     return ips, ports
 
 
-def call_packets(seed: int, mix: dict, packets: int, pmax: int,
-                 flows: int, pool_seed: int) -> dict:
-    """The flat batch one materialized call offers, as numpy arrays."""
-    key = jax.random.key(seed)
+def _call_batch(key, mix: dict, packets: int, pmax: int, flows: int,
+                pool_seed: int) -> dict:
+    """The flat batch one materialized call offers, as jax arrays."""
     pkts = _mix_batch(key, packets, mix, pmax)
     if flows:
         ips, ports = flow_pool(flows, pool_seed)
         idx = jax.random.randint(jax.random.fold_in(key, 1), (packets,), 0,
                                  flows)
         pkts = dict(pkts, src_ip=ips[idx], src_port=ports[idx])
+    return pkts
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_draw(devices: int, sizes: tuple, probs: tuple, packets: int,
+                  pmax: int, flows: int, pool_seed: int):
+    """``_call_batch`` jitted, every output sharded along its packet axis
+    over the first ``devices`` devices."""
+    mesh = Mesh(np.asarray(jax.devices()[:devices]), ("packets",))
+    mix = dict(sizes=sizes, probs=probs)
+    return jax.jit(
+        lambda key: _call_batch(key, mix, packets, pmax, flows, pool_seed),
+        out_shardings=NamedSharding(mesh, PartitionSpec("packets")))
+
+
+def call_packets(seed: int, mix: dict, packets: int, pmax: int,
+                 flows: int, pool_seed: int, devices: int = 1) -> dict:
+    """The flat batch one materialized call offers, as numpy arrays;
+    drawn sharded over ``devices`` devices where that is above 1."""
+    key = jax.random.key(seed)
+    if devices == 1:
+        pkts = _call_batch(key, mix, packets, pmax, flows, pool_seed)
+    else:
+        pkts = _sharded_draw(devices, tuple(mix["sizes"]),
+                             tuple(mix["probs"]), packets, pmax, flows,
+                             pool_seed)(key)
     return {f: np.asarray(v) for f, v in pkts.items()}
 
 

@@ -90,10 +90,21 @@ class Run:
     def window_s(self) -> float:
         return self.calls[-1].end - self.calls[0].start
 
+    @property
+    def calls_s(self) -> float:
+        """The window's time inside its calls: the window less the
+        benchmark's own copies between them."""
+        return sum(c.end - c.start for c in self.calls)
+
 
 def hold_to_config(cell: Cell, facts: dict) -> None:
     """Refuse a configuration file that says something else than what the
-    program builds from it: the file is the reference's only source."""
+    program builds from it: the file is the reference's only source.
+    The program's pipes must also run sharded over just as many devices
+    as the file's ``devices`` (1 where it has none) and the cell's
+    ``chips`` say: the program would run a pipe count that does not
+    divide its devices on one device, and a cell on four chips that ran
+    on one would measure one."""
     cfg, park, tr = cell.config, facts["park"], cell.traffic
     mine = cfg["park"]
     theirs = dict(capacity=park.capacity, max_exp=park.max_exp,
@@ -123,6 +134,13 @@ def hold_to_config(cell: Cell, facts: dict) -> None:
     if wrong:
         raise CellError(f"{cfg['name']}: the configuration files disagree "
                         f"with the program on {wrong}")
+    chips, asked = cell.workload["chips"], cfg.get("devices", 1)
+    if facts["devices"] != asked or facts["devices"] != chips:
+        raise CellError(
+            f"{cfg['name']}: the program runs {cfg.get('pipes', 1)} pipes "
+            f"on {facts['devices']} device(s), where the configuration "
+            f"asks for {asked} and the cell {cell.workload['name']!r} for "
+            f"{chips} chip(s)")
 
 
 class Compiles:
@@ -285,11 +303,16 @@ def main(args, t_start: float, require_tpu: bool = True) -> int:
     from bench.drivers import DRIVERS, LIMIT
     driver = DRIVERS[cell.config["engine"]](cell.config, cell.traffic,
                                             args.seed)
-    hold_to_config(cell, driver.program_facts())
+    try:
+        hold_to_config(cell, driver.program_facts())
+    except CellError as e:
+        log(f"cannot run the cell: {e}")
+        return 2
 
     run, in_window = measure(cell, driver, args.seconds, bool(args.trace),
                              t_start, peaks, log)
-    log(f"window: {len(run.calls)} calls in {run.window_s:.3f} s "
+    log(f"window: {len(run.calls)} calls in {run.window_s:.3f} s, "
+        f"{run.calls_s:.3f} s of it in the calls "
         f"({', '.join(f'{c.end - c.start:.3f}' for c in run.calls)}), "
         f"programs compiled inside it: {in_window or 'none'}")
     kind = "per_layer" if args.trace else "end_to_end"

@@ -3,7 +3,8 @@ the lane's admission and the lane rows joined to the NF-bound chunk) per
 pipe-step of the profiled slice, in us: the counted device ops whose op
 name carries the stage's scope, over the slice's pipe-steps.  Not read
 from a trace that lost kernel launches, nor from a program without the
-engine's stage scopes."""
+engine's stage scopes.  On several chips the ops' time is summed over
+the chips, as the pipe-steps are over every chip's pipes."""
 from bench import stages
 
 

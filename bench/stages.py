@@ -127,7 +127,7 @@ def outer_loops(names: dict) -> set:
 
 
 def stage_ns(trace, names: dict) -> dict | None:
-    """Device ns by stage, averaged over the devices used, each ns
+    """Device ns by stage, summed over the devices used, each ns
     counted once: an op of the engine's module counts its own time, so a
     control-flow op counts what its body ops' time leaves of its span
     (loop control, or a body the trace does not list op by op), for the
@@ -136,17 +136,21 @@ def stage_ns(trace, names: dict) -> dict | None:
     ``engine.tally``) are ``other``.
 
     None where no op carries a stage scope, and where the module is not
-    the one that ran: the trace lacks the engine's outer loop, or the
-    loop keeps more than ``LOOP_OWN`` of its span for itself, the time
-    of body ops the module does not know."""
+    the one that ran: a device's ops lack the engine's outer loop (as
+    ops that come without their HLO text do), or the loop keeps more
+    than ``LOOP_OWN`` of its span for itself, the time of body ops the
+    module does not know."""
     if trace is None or not trace.devices():
         return None
     mine = {op: found for op in trace.op_ns
             if (found := _own(op, names)) is not None}
     traced = {name: trace.op_ns[op] for op, (name, _) in mine.items()}
     loops = outer_loops(names) & set(traced)
-    if not loops:
-        return None
+    for dev in trace.devices():
+        ran = {mine[op][0] for op in trace.device_ops.get(dev, ())
+               if op in mine}
+        if not loops & ran:
+            return None
     out = dict.fromkeys(STAGES + ("other",), 0.0)
     named = False
     for op, ns in trace.op_ns.items():
@@ -162,10 +166,7 @@ def stage_ns(trace, names: dict) -> dict | None:
             stage = None
         named = named or stage is not None
         out[stage if stage in STAGES else "other"] += ns
-    if not named:
-        return None
-    n = len(trace.devices())
-    return {k: v / n for k, v in out.items()}
+    return out if named else None
 
 
 def engine_names(cell) -> dict:
@@ -217,7 +218,9 @@ def _stage_ns(run) -> dict | None:
 
 def stage_us_per_pipe_step(run, stage: str) -> float | None:
     """Device time of one stage per pipe-step of the profiled slice, in
-    us; read only from a trace that holds every kernel launch."""
+    us; read only from a trace that holds every kernel launch.  On
+    several chips the stage's time is summed over them, as the
+    pipe-steps are summed over every chip's pipes."""
     if run.traced is None or not run.traced.traced_steps:
         return None
     by_stage = _stage_ns(run)

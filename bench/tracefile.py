@@ -8,8 +8,9 @@ module keeps only
   * each device's busy time, as the union of the intervals in which an
     XLA operation ran on it (the device plane's ``XLA Ops`` line);
   * the total device time of every operation name, with the text by
-    which a kernel is found (its name and its string statistics), and the
-    interval of every launch of a Pallas kernel;
+    which a kernel is found (its name and its string statistics), the
+    interval of every launch of a Pallas kernel, and the names each
+    device ran;
   * the benchmark's own spans (host events named ``bench.*``) and the
     other host events of the thread that made them, which say what the
     host was doing while the device sat idle.
@@ -46,6 +47,8 @@ class Trace:
     dropped: int | None = None   # device events lost from here on
     kernels: dict = dataclasses.field(default_factory=dict)
     # Pallas op name -> (start, end) of each of its launches
+    device_ops: dict = dataclasses.field(default_factory=dict)
+    # device plane -> the set of op names it ran
 
     def devices(self) -> list[str]:
         return sorted(d for d, iv in self.busy.items() if iv)
@@ -124,10 +127,11 @@ def load(path: str) -> Trace:
 
     data = ProfileData.from_file(path)
     busy, op_ns, op_count, op_text, kernels = {}, {}, {}, {}, {}
+    device_ops: dict = {}
     spans, host_lines, dropped = [], [], []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
-            intervals = []
+            intervals, ran = [], device_ops.setdefault(plane.name, set())
             for line in plane.lines:
                 if line.name != OPS_LINE:
                     dropped += [int(ev.start_ns) for ev in line.events
@@ -143,6 +147,7 @@ def load(path: str) -> Trace:
                         op_text[name] = _text(ev)
                     op_ns[name] += d
                     op_count[name] += 1
+                    ran.add(name)
                     if PALLAS in op_text[name]:
                         kernels.setdefault(name, []).append((s, s + d))
             busy[plane.name] = union(intervals)
@@ -158,7 +163,8 @@ def load(path: str) -> Trace:
                                       if not ev[0].startswith(SPAN_PREFIX))
     return Trace(busy=busy, op_ns=op_ns, op_count=op_count, op_text=op_text,
                  spans=sorted(spans, key=lambda x: x[1]), host=host_lines,
-                 dropped=min(dropped) if dropped else None, kernels=kernels)
+                 dropped=min(dropped) if dropped else None, kernels=kernels,
+                 device_ops=device_ops)
 
 
 def window(trace: Trace) -> tuple[int, int]:
@@ -207,6 +213,19 @@ def kernel_ns(trace: Trace, lo: int, hi: int, besides=()) -> int:
 def payload_launches(trace: Trace) -> int:
     """Launches of the payload store and fetch kernels in the window."""
     return len(launches(trace, *window(trace), NAMED_KERNELS))
+
+
+def named_on_every_device(trace: Trace) -> bool:
+    """Whether every device shows its payload kernels, where any device
+    does: the devices of a sharded program run the same ones.  A device
+    whose ops come without their HLO text (on four chips, the first
+    chip's are at times named ``region.N``, but for the kernels that
+    keep their own name) hides its payload kernels and its stages."""
+    payload = {op for op in trace.kernels
+               if not any(n in trace.op_text[op] for n in NAMED_KERNELS)}
+    found = [bool(trace.device_ops.get(d, set()) & payload)
+             for d in trace.devices()]
+    return all(found) or not any(found)
 
 
 def complete(trace: Trace, steps: int) -> bool:

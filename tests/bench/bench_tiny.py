@@ -23,6 +23,13 @@ def matrix_config() -> dict:
     return cfg
 
 
+def fabric_config() -> dict:
+    """The ToR's pipes sharded over four devices: 8 pipes, 2 a device."""
+    cfg = matrix_config()
+    cfg.update(pipes=8, devices=4, packets_per_pipe=256)
+    return cfg
+
+
 def stream_config() -> dict:
     cfg = copy.deepcopy(_load("configs/nat1_stream.json"))
     cfg.update(steps_per_call=32, chunk=64, segment_len=16, reservoir=256,

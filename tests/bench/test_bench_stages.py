@@ -117,7 +117,8 @@ def _trace(drop=()):
               ("repro.dispatch", 290, 310), ("repro.finalize", 310, 940),
               ("np.asarray(jax.Array)", 800, 900),
               ("repro.nf_cycles", 940, 990)],
-        kernels={pallas: [(320, 330)]})
+        kernels={pallas: [(320, 330)]},
+        device_ops={"/device:TPU:0": set(ops)})
 
 
 def _run(trace, whole=True, complete=True, steps=10):
@@ -157,7 +158,8 @@ def test_each_device_ns_counts_once():
     tr = _trace()
     by = stages.stage_ns(tr, _names())
     assert by == WANT
-    assert sum(by.values()) == TF.busy_ns(tr, *TF.window(tr))
+    assert sum(by.values()) == \
+        TF.busy_ns(tr, *TF.window(tr)) * len(tr.devices())
 
 
 def test_a_loop_whose_body_the_trace_lacks_counts_whole():
@@ -172,6 +174,81 @@ def test_stage_metrics_per_pipe_step():
     # tally, loop control, generation and the eager result op are the rest
     assert reader("other_device_share")(run) == pytest.approx(
         100 * 70 / 530)
+
+
+def _two_devices(tr):
+    """The same call on two devices, as ``tracefile.load`` reduces it:
+    each op's time summed over both, busy intervals kept per device.
+    The second runs the same ops, and is busy for 500 of the 530 ns."""
+    return TF.Trace(busy={"/device:TPU:0": tr.busy["/device:TPU:0"],
+                          "/device:TPU:1": [(300, 800)]},
+                    op_ns={op: 2 * ns for op, ns in tr.op_ns.items()},
+                    op_count={op: 2 * n for op, n in tr.op_count.items()},
+                    op_text=tr.op_text, spans=tr.spans, host=tr.host,
+                    kernels={op: 2 * ivs for op, ivs in tr.kernels.items()},
+                    device_ops={"/device:TPU:0": set(tr.op_ns),
+                                "/device:TPU:1": set(tr.op_ns)})
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_per_pipe_step_time_counts_every_device(devices):
+    """The pipe-steps are every device's pipes' steps, and the device
+    time is summed over the devices too: two devices read twice the
+    stage time of one over the same pipe-steps, and one reads what it
+    always has.  The shares stay shares."""
+    tr = _trace() if devices == 1 else _two_devices(_trace())
+    run = _run(tr)
+    assert stages.stage_ns(tr, _names()) == {
+        k: devices * ns for k, ns in WANT.items()}
+    for stage in stages.STAGES:
+        assert reader(f"{stage}_us_per_pipe_step")(run) == pytest.approx(
+            devices * WANT[stage] / 1e3 / 10)
+    busy = 530 if devices == 1 else 530 + 500
+    assert reader("device_us_per_pipe_step")(run) == pytest.approx(
+        busy / 1e3 / 10)
+    assert reader("other_device_share")(run) == pytest.approx(
+        100 * 70 / 530)
+    assert TF.named_on_every_device(tr)
+    assert reader("kernel_device_share")(run) == pytest.approx(
+        100 * devices * 10 / busy)
+
+
+@pytest.mark.parametrize("named_kernel", [False, True])
+def test_a_device_whose_ops_go_unnamed_reads_nothing(named_kernel):
+    """A second device that runs the same call, but whose ops come as
+    ``region.N`` without their HLO text: its loop, stages and payload
+    kernels are not found, so every stage and kernel reader reads
+    nothing rather than a share of the devices, in a trace that lost no
+    launch.  So too where a kernel that keeps its own name (the CRC)
+    shows on both devices, as on a four-chip TPU trace.  Busy time needs
+    no names."""
+    one = _trace()
+    unnamed = {f"region.{i}": ns for i, ns in enumerate(one.op_ns.values())}
+    crc = '%crc16_kernel.40 = s32[8,128] custom-call(%p.1), ' \
+        'custom_call_target="tpu_custom_call"'
+    both = {crc: 20} if named_kernel else {}
+    kernels = dict(one.kernels, **{op: [(850, 860), (850, 860)]
+                                   for op in both})
+    tr = TF.Trace(busy={"/device:TPU:0": one.busy["/device:TPU:0"],
+                        "/device:TPU:1": [(300, 800)]},
+                  op_ns={**one.op_ns, **unnamed, **both},
+                  op_count={**one.op_count, **dict.fromkeys(unnamed, 1),
+                            **dict.fromkeys(both, 2)},
+                  op_text={**one.op_text, **{op: op for op in unnamed},
+                           **{op: op for op in both}},
+                  spans=one.spans, host=one.host, kernels=kernels,
+                  device_ops={"/device:TPU:0": set(one.op_ns) | set(both),
+                              "/device:TPU:1": set(unnamed) | set(both)})
+    assert TF.named_on_every_device(one)
+    assert not TF.named_on_every_device(tr)
+    assert TF.complete(tr, 1)      # no launch of the named device lost
+    assert stages.stage_ns(tr, _names()) is None
+    run = _run(tr)
+    for name in STAGE_METRICS + ("kernel_device_share",
+                                 "payload_kernel_roofline"):
+        assert reader(name)(run) is None, name
+    assert reader("device_us_per_pipe_step")(run) == pytest.approx(
+        (530 + 500) / 1e3 / 10)
 
 
 def test_ops_of_other_programs_and_unscoped_ops_land_in_other():

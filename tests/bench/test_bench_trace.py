@@ -29,7 +29,9 @@ def _trace():
                ("bench.segment", 200, 250)],
         host=[("PjitFunction(run)", 60, 110), ("np.asarray", 205, 240)],
         kernels={"custom-call.7": [(20, 25), (30, 35)],
-                 "custom-call.9": [(130, 150)]})
+                 "custom-call.9": [(130, 150)]},
+        device_ops={"/device:TPU:0": {"fusion.1", "custom-call.7",
+                                      "custom-call.9", "while.3"}})
 
 
 def test_union_overlap_gaps():
@@ -73,6 +75,17 @@ def _run(trace, whole=True, complete=True):
     return types.SimpleNamespace(trace=trace, calls=calls, cell=cell,
                                  peaks={"hbm_bytes_per_s": 819e9},
                                  traced=calls[0], trace_complete=complete)
+
+
+def test_sim_pps_reads_the_calls_time_not_the_gaps_between_them():
+    """Two 1-s calls with 3 s of the check's copies between them: 2000
+    packets over the 2 s of the calls, where the window spans 5 s."""
+    calls = [types.SimpleNamespace(start=0.0, end=1.0, packets=1000),
+             types.SimpleNamespace(start=4.0, end=5.0, packets=1000)]
+    run = harness.Run(cell=None, setup_s=0.0, calls=calls, peak_bytes=0,
+                      peaks={})
+    assert run.window_s == 5.0 and run.calls_s == 2.0
+    assert reader("sim_pps")(run) == pytest.approx(1000.0)
 
 
 def test_readers_on_a_made_up_trace():
