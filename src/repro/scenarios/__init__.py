@@ -12,17 +12,18 @@ from repro.scenarios.matrix import pipeline_grid, recirc_grid
 from repro.scenarios.registry import family, names, register
 from repro.scenarios.runner import (OracleMismatch, ScenarioResult,
                                     default_rows, engine_programs,
-                                    run_matrix, verify_oracle)
+                                    run_matrix, traffic_programs,
+                                    verify_oracle)
 from repro.scenarios.spec import (ScenarioSpec, build_chain, compile_key,
-                                  grid, make_packets, resolve_workload,
-                                  steer)
+                                  draw_packets, grid, make_packets,
+                                  resolve_workload, sharded_traffic, steer)
 
 __all__ = [
     "family", "names", "register", "pipeline_grid", "recirc_grid",
     "OracleMismatch", "ScenarioResult", "default_rows", "engine_programs",
-    "run_matrix", "verify_oracle",
-    "ScenarioSpec", "build_chain", "compile_key", "grid", "make_packets",
-    "resolve_workload", "steer",
+    "run_matrix", "traffic_programs", "verify_oracle",
+    "ScenarioSpec", "build_chain", "compile_key", "draw_packets", "grid",
+    "make_packets", "resolve_workload", "sharded_traffic", "steer",
     "adversarial_family", "bounds_for", "degradation_block",
     "degradation_metrics",
 ]

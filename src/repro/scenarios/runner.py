@@ -32,15 +32,18 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import divides_axis
 from repro.scenarios.spec import (ScenarioSpec, build_chain, compile_key,
-                                  make_packets, steer)
+                                  make_packets, sharded_traffic, steer)
 from repro.switchsim import engine as E
+from repro.switchsim import fabric
 from repro.switchsim import faults as F
 from repro.switchsim.results import flat_summary
 from repro.switchsim.simulate import simulate_loop
 from repro.switchsim.telemetry import LinkTelemetry, sum_telemetry
 from repro.core import counters as C
 from repro.core.packet import PacketBatch
+from repro.traffic import generator as T
 
 
 @dataclasses.dataclass
@@ -99,15 +102,36 @@ class _Prepared:
     steer_stats: dict
     n_pipes: int
     faults: F.FaultArrays = None  # per-pipe masks over the steered steps
+    steps: int = 0                # per-pipe steps of the steered traces
+    counts: object = None         # per-pipe arrivals, drawn per shard
+
+    def stats(self) -> dict:
+        """``steer``'s stats, from the per-shard counts where the point
+        was drawn per shard."""
+        if self.steer_stats is None:
+            self.steer_stats = T.steer_stats(self.counts,
+                                             self.steps * self.spec.chunk)
+        return self.steer_stats
 
 
 def _prepare(spec: ScenarioSpec) -> _Prepared:
+    """A point's chain, steps and fault masks, and on one device its
+    traffic, drawn eagerly and steered (``make_packets``, ``steer``).
+    A point sharded over several devices draws nothing here: its compile
+    group's traffic program draws, steers and exchanges it per shard
+    (``_draw``), and the chain's rules, where they come from the
+    traffic, from the source IPs drawn alone."""
+    if spec.devices > 1:
+        steps = T.pipe_trace_steps(spec.packets, spec.pipes, spec.chunk)
+        fa = F.resolve(spec.fault, pipes=spec.pipes, steps=steps)
+        return _Prepared(spec, None, build_chain(spec, None), None, None,
+                         spec.pipes, fa, steps)
     pkts = make_packets(spec)
     chain = build_chain(spec, pkts)
     traces, stats = steer(spec, pkts)
     steps = jax.tree.leaves(traces)[0].shape[1]
     fa = F.resolve(spec.fault, pipes=spec.pipes, steps=steps)
-    return _Prepared(spec, pkts, chain, traces, stats, spec.pipes, fa)
+    return _Prepared(spec, pkts, chain, traces, stats, spec.pipes, fa, steps)
 
 
 def _cat_pipe_axis(traces_list):
@@ -126,19 +150,59 @@ def _grouped(specs):
     with jax.profiler.TraceAnnotation("repro.dispatch"):
         groups: dict = {}
         for i, p in enumerate(prepared):
-            steps = jax.tree.leaves(p.traces)[0].shape[1]
-            key = compile_key(p.spec, p.chain, steps)
+            key = compile_key(p.spec, p.chain, p.steps)
             groups.setdefault(key, []).append(i)
     return prepared, groups
 
 
-def _group_args(key, members, prepared) -> tuple[tuple, dict]:
+def _traffic(key, members, prepared):
+    """The group's per-shard traffic program (``spec.sharded_traffic``)
+    as ``(fn, args)``, or None where the group draws eagerly: on one
+    device, where its pipes do not divide the devices (the engine then
+    runs it on one, ``fabric.resolve_devices``), or where a member's
+    packets do not."""
+    devices = key[-1]
+    specs = [prepared[i].spec for i in members]
+    pipes = sum(s.pipes for s in specs)
+    if devices == 1 or not divides_axis(pipes, devices) \
+            or any(s.packets % devices for s in specs):
+        return None
+    fabric.resolve_devices(pipes, devices)  # raises where fewer are visible
+    return sharded_traffic(specs, devices)
+
+
+def _draw(key, members, prepared):
+    """Give the group's members their traces: by the group's traffic
+    program, whose stacked traces are returned, else eagerly (None)."""
+    program = _traffic(key, members, prepared)
+    if program is None:
+        for i in members:
+            p = prepared[i]
+            if p.traces is None:
+                p.pkts = make_packets(p.spec)
+                p.traces, p.steer_stats = steer(p.spec, p.pkts)
+        return None
+    fn, args = program
+    traces, counts = fn(*args)
+    lo = 0
+    for i, n in zip(members, counts):
+        p = prepared[i]
+        hi = lo + p.n_pipes
+        p.traces = traces if len(members) == 1 else jax.tree.map(
+            lambda a, lo=lo, hi=hi: a[lo:hi], traces)
+        p.counts, lo = n, hi
+    return traces
+
+
+def _group_args(key, members, prepared, traces=None) -> tuple[tuple, dict]:
     """``engine.run_pipes``'s arguments for one compile group: its
-    members' traces and fault masks stacked on one pipe axis."""
+    members' traces (``traces``, where its traffic program stacked them)
+    and fault masks stacked on one pipe axis."""
     (cfg, chain, window, _chunk, _steps, _pmax, explicit_drops,
      _lane, backend, devices) = key
     with jax.profiler.TraceAnnotation("repro.dispatch"):
-        stacked = _cat_pipe_axis([prepared[i].traces for i in members])
+        stacked = (traces if traces is not None else
+                   _cat_pipe_axis([prepared[i].traces for i in members]))
         # fault masks ride the same stacked pipe axis as the traces —
         # healthy members contribute all-True columns, so one compiled
         # program serves faulted and healthy points alike (DESIGN.md §10)
@@ -153,15 +217,36 @@ def _group_args(key, members, prepared) -> tuple[tuple, dict]:
         faults=stacked_faults, devices=devices)
 
 
+def _engine_args(specs):
+    """Each compile group's key, members and ``run_pipes`` arguments,
+    its traffic drawn (under ``repro.prepare``) on the way."""
+    prepared, groups = _grouped(specs)
+    for key, members in groups.items():
+        with jax.profiler.TraceAnnotation("repro.prepare"):
+            traces = _draw(key, members, prepared)
+        yield prepared, key, members, _group_args(key, members, prepared,
+                                                  traces)
+
+
+def traffic_programs(specs) -> list[tuple]:
+    """The per-shard traffic programs ``run_matrix(specs)`` runs, one per
+    compile group that draws per shard (``spec.sharded_traffic``), each
+    as ``(fn, args)``; ``fn.lower(*args).compile().as_text()`` names the
+    ``traffic.draw``, ``traffic.steer`` and ``traffic.exchange`` scope of
+    every device op (DESIGN.md §14).  Draws nothing."""
+    prepared, groups = _grouped(specs)
+    return [program for key, members in groups.items()
+            if (program := _traffic(key, members, prepared)) is not None]
+
+
 def engine_programs(specs) -> list[tuple]:
     """The engine programs ``run_matrix(specs)`` runs, one per compile
     group, each as ``engine.pipes_program`` gives it: ``(fn, args)``,
     whose ``fn.lower(*args).compile().as_text()`` names the stage of
-    every device op (DESIGN.md §14)."""
-    prepared, groups = _grouped(specs)
-    return [E.pipes_program(*args, **kw) for args, kw in
-            (_group_args(key, members, prepared)
-             for key, members in groups.items())]
+    every device op (DESIGN.md §14).  The arguments hold the groups'
+    traffic, drawn as ``run_matrix`` draws it."""
+    return [E.pipes_program(*args, **kw)
+            for _, _, _, (args, kw) in _engine_args(specs)]
 
 
 def run_matrix(specs, time_runs: bool = False,
@@ -177,10 +262,8 @@ def run_matrix(specs, time_runs: bool = False,
     """
     # host phases as disjoint profiler spans (DESIGN.md §14); run_pipes
     # opens its own dispatch and finalize spans between these
-    prepared, groups = _grouped(specs)
-    results: list = [None] * len(prepared)
-    for key, members in groups.items():
-        args, kw = _group_args(key, members, prepared)
+    results: list = [None] * len(specs)
+    for prepared, key, members, (args, kw) in _engine_args(specs):
         chain, backend = args[1], kw["backend"]
         run = functools.partial(E.run_pipes, *args, **kw)
         res = run()
@@ -219,7 +302,7 @@ def run_matrix(specs, time_runs: bool = False,
                     per_pipe_nf_counters=per_nf,
                     per_pipe_occ_series=res.per_pipe_occ_series[lo:hi],
                     gain=E.goodput_gain_from_telemetry(tel),
-                    steer_stats=p.steer_stats,
+                    steer_stats=p.stats(),
                     nf_cycles=nf_cycles,
                     wall_s=group_wall / len(members),
                     group_size=len(members),
@@ -264,7 +347,12 @@ def verify_oracle(result: ScenarioResult, faults=True,
     # reuse the traffic/chain/traces the result was computed from; a
     # result reconstructed without them (deserialized, hand-built) still
     # verifies via deterministic re-preparation
-    p = result.prepared if result.prepared is not None else _prepare(spec)
+    p = result.prepared
+    if p is None:
+        prepared, groups = _grouped([spec])
+        for key, members in groups.items():
+            _draw(key, members, prepared)
+        p = prepared[0]
     cfg = spec.park_config()
     from repro.core.packet import from_time_major
     # contiguous shard of each pipe index, for mismatch localization

@@ -18,9 +18,11 @@ the compiled engine — identical across workload axes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.backend import BackendConfig, as_config
@@ -168,19 +170,18 @@ def resolve_workload(ws: WorkloadSpec) -> T.Workload:
     raise ValueError(f"unknown workload spec {ws!r}")
 
 
-def make_packets(spec: ScenarioSpec) -> PacketBatch:
-    """Deterministic traffic for one scenario point.
-
-    The PRNG key folds in only ``seed`` — two specs with equal
-    (workload, packets, pmax, flows, seed) produce bit-identical traffic
-    no matter how the rest of the grid differs, so recirc-on/off pairs
-    compare the same packets.
-    """
+def draw_packets(spec: ScenarioSpec, key: jax.Array,
+                 pool=None) -> PacketBatch:
+    """The flat batch of one scenario point, drawn from ``key``: the
+    workload's packets, their sources taken from the flow pool when the
+    spec constrains flows (``pool``, ``traffic.generator.flow_pool``'s
+    ``(ips, ports)``, is drawn here where not given).  Traceable once the
+    pool is given, so ``sharded_traffic`` draws under jit with the same
+    calls in the same order."""
     wl = resolve_workload(spec.workload)
-    key = jax.random.key(spec.seed)
     pkts = wl.make_batch(key, spec.packets, pmax=spec.pmax)
     if spec.flows:
-        ips, ports = T.flow_pool(spec.flows)
+        ips, ports = T.flow_pool(spec.flows) if pool is None else pool
         idx = jax.random.randint(jax.random.fold_in(key, 1),
                                  (spec.packets,), 0, spec.flows)
         # both halves of the NAT flow key (src_ip, src_port) come from the
@@ -189,16 +190,41 @@ def make_packets(spec: ScenarioSpec) -> PacketBatch:
     return pkts
 
 
-def firewall_rules(spec: ScenarioSpec, pkts: PacketBatch) -> tuple[int, ...]:
+def make_packets(spec: ScenarioSpec) -> PacketBatch:
+    """Deterministic traffic for one scenario point, drawn eagerly on the
+    default device (the whole batch at once).
+
+    The PRNG key folds in only ``seed`` — two specs with equal
+    (workload, packets, pmax, flows, seed) produce bit-identical traffic
+    no matter how the rest of the grid differs, so recirc-on/off pairs
+    compare the same packets.  A point sharded over several devices does
+    not come here: ``sharded_traffic`` draws its batch per shard, bit for
+    bit this one (DESIGN.md §12).
+    """
+    return draw_packets(spec, jax.random.key(spec.seed))
+
+
+def firewall_rules(spec: ScenarioSpec,
+                   pkts: PacketBatch | None) -> tuple[int, ...]:
     """Blocked-IP list: from the flow pool when flows are constrained
     (workload-independent -> chains shareable across workload axes),
-    otherwise the seed benches' rule source (first N unique src IPs)."""
+    otherwise the seed benches' rule source (first N unique src IPs).
+    Without ``pkts`` (a point drawn per shard) the source IPs are drawn
+    alone: the batch's header draw, jitted, whose payload XLA leaves
+    out."""
     if spec.flows:
         ips, _ = T.flow_pool(spec.flows)
         return tuple(int(ip) for ip in
                      np.asarray(ips[:spec.fw_rules]).tolist())
+    src_ip = (pkts.src_ip if pkts is not None else
+              _src_ips(_traffic_shape(spec))(jax.random.key(spec.seed)))
     return tuple(int(ip) for ip in
-                 np.unique(np.asarray(pkts.src_ip))[:spec.fw_rules].tolist())
+                 np.unique(np.asarray(src_ip))[:spec.fw_rules].tolist())
+
+
+@functools.lru_cache(maxsize=None)
+def _src_ips(shape: ScenarioSpec):
+    return jax.jit(lambda key: draw_packets(shape, key).src_ip)
 
 
 _NF_NAMES = ("fw", "nat", "lb", "macswap")
@@ -222,11 +248,14 @@ def build_chain(spec: ScenarioSpec, pkts: PacketBatch) -> Chain:
 
 
 def steer(spec: ScenarioSpec, pkts: PacketBatch):
-    """Shard a scenario's traffic into its (P, T, chunk, ...) traces.
+    """Shard a scenario's traffic into its (P, T, chunk, ...) traces, on
+    the device that holds ``pkts`` (``make_packets``' eager draw).
 
     Single-pipe scenarios skip hashing entirely (identity + tail padding
     via ``to_time_major``); multi-pipe scenarios go through the §6.3.2
-    flow steering.  Returns ``(traces, steer_stats)``.
+    flow steering.  Returns ``(traces, steer_stats)``.  A point sharded
+    over several devices is steered per shard instead, inside
+    ``sharded_traffic``'s program, to the same traces and stats.
     """
     if spec.pipes == 1:
         trace = to_time_major(pkts, spec.chunk)
@@ -239,6 +268,90 @@ def steer(spec: ScenarioSpec, pkts: PacketBatch):
         lambda a: a.reshape((spec.pipes, a.shape[1] // spec.chunk,
                              spec.chunk) + a.shape[2:]), shards)
     return traces, stats
+
+
+def _traffic_shape(spec: ScenarioSpec) -> ScenarioSpec:
+    """The spec with the fields that do not shape its traffic program
+    (name, seed) at fixed values: the program's cache key."""
+    return dataclasses.replace(spec, name="traffic", seed=0)
+
+
+def sharded_traffic(specs, devices: int):
+    """The per-shard traffic program of one compile group and its
+    arguments: ``(fn, args)``, where ``fn(*args)`` returns
+    ``(traces, counts)``.  ``traces`` are the members' (P, T, chunk, ...)
+    traces stacked on one pipe axis, sharded over ``devices`` devices as
+    the engine's ``shard_map`` takes them (``fabric.SWITCH``); ``counts``
+    holds each member's per-pipe arrivals, whose stats
+    (``traffic.generator.steer_stats``) are ``steer``'s.
+
+    One jitted program on the ``("switch",)`` mesh (DESIGN.md §12):
+
+      * ``traffic.draw``: each device draws its contiguous share of each
+        member's flat batch along the packet axis, by ``draw_packets``'
+        calls (partitionable threefry gives the bits of the whole draw);
+      * ``traffic.steer``: each device steers its own packets
+        (``traffic.generator.steer_shard``); only per-pipe counts cross
+        devices;
+      * ``traffic.exchange``: every packet moves to the device that holds
+        its pipe, at its slot (``exchange_rows``).
+
+    The traces equal ``make_packets`` + ``steer`` of each member bit for
+    bit.  Each member's packets must divide over the devices, and the
+    members' pipes together too.
+    """
+    fn = _traffic_program(tuple(_traffic_shape(s) for s in specs), devices)
+    return fn, tuple(jax.random.key(s.seed) for s in specs)
+
+
+@functools.lru_cache(maxsize=None)
+def _traffic_program(shapes: tuple, devices: int):
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.switchsim import fabric
+
+    mesh = fabric.switch_mesh(devices)
+    pools = [T.flow_pool(s.flows) if s.flows else None for s in shapes]
+    steps = T.pipe_trace_steps(shapes[0].packets, shapes[0].pipes,
+                               shapes[0].chunk)
+    cap = steps * shapes[0].chunk            # every member's pipe capacity
+    per_device = sum(s.pipes for s in shapes) // devices
+    slots = per_device * cap
+
+    def per_shard(*batches):
+        dest, slot, counts, offset = [], [], [], 0
+        with jax.named_scope("traffic.steer"):
+            for s, pkts in zip(shapes, batches):
+                pipe, pos, n = T.steer_shard(pkts, s.pipes, fabric.SWITCH_AXIS)
+                at = (offset + pipe) * cap + pos
+                dest.append(jnp.where(pos < cap, at // slots, devices))
+                slot.append(at % slots)
+                counts.append(n)
+                offset += s.pipes
+        with jax.named_scope("traffic.exchange"):
+            rows = T.exchange_rows(
+                jax.tree.map(lambda *a: jnp.concatenate(a),
+                             *[T.pack_rows(b) for b in batches]),
+                jnp.concatenate(dest), jnp.concatenate(slot), slots,
+                fabric.SWITCH_AXIS, devices)
+            traces = jax.tree.map(
+                lambda a: a.reshape((per_device, steps, shapes[0].chunk)
+                                    + a.shape[1:]),
+                T.unpack_rows(rows))
+        return traces, tuple(counts)
+
+    def program(*keys):
+        batches = []
+        with jax.named_scope("traffic.draw"):
+            for s, key, pool in zip(shapes, keys, pools):
+                batches.append(jax.lax.with_sharding_constraint(
+                    draw_packets(s, key, pool),
+                    NamedSharding(mesh, fabric.SWITCH)))
+        return jax.shard_map(
+            per_shard, mesh=mesh, in_specs=(fabric.SWITCH,) * len(shapes),
+            out_specs=(fabric.SWITCH, PartitionSpec()),
+            check_vma=False)(*batches)
+
+    return jax.jit(program)
 
 
 def grid(base: ScenarioSpec, name_fmt: str, **axes) -> list[ScenarioSpec]:

@@ -16,6 +16,14 @@ module puts a ``jax.sharding`` mesh under it:
     ys) carries the pipe axis leading, so ONE ``PartitionSpec("switch")``
     is the whole contract — no collectives, no replicated outputs, no
     cross-shard traffic of any kind;
+  * ``SWITCH``, ``PartitionSpec("switch")``, is the one contract the
+    engine and the traffic program share: the pipe axis of every trace
+    leaf leads and shards contiguously over the mesh.  A sharded point's
+    traffic is drawn, steered and exchanged per shard by
+    ``scenarios.spec.sharded_traffic``, one jitted program over the same
+    mesh, so its traces arrive already sharded as the engine's
+    ``shard_map`` takes them and no device holds the whole call's
+    payload draw (one-device points keep the eager draw);
   * ``resolve_devices(pipes, devices)`` is the guarded
     fallback-to-replication (``distributed.sharding.divides_axis``, the
     same predicate the model-parallel rules use): when the pipe count
@@ -56,6 +64,7 @@ from jax.sharding import AxisType, Mesh, PartitionSpec
 from repro.distributed.sharding import axis_size, divides_axis
 
 SWITCH_AXIS = "switch"
+SWITCH = PartitionSpec(SWITCH_AXIS)
 
 
 def fabric_devices() -> int:
@@ -126,9 +135,8 @@ def shard_over_switch(run, devices: int):
     """
     mesh = switch_mesh(devices)
     assert axis_size(mesh, SWITCH_AXIS) == devices
-    spec = PartitionSpec(SWITCH_AXIS)
     # check_vma=False: the body is a pure per-pipe map with no collectives
     # and no replicated outputs, so the varying-axes checker has nothing to
     # prove and only adds tracing overhead on wide fabrics.
-    return jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                         out_specs=spec, check_vma=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=(SWITCH,) * 4,
+                         out_specs=SWITCH, check_vma=False)

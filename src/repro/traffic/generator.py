@@ -31,8 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.packet import (HDR_BYTES, PacketBatch, gather_rows,
-                               make_udp_batch)
+from repro.core.packet import (HDR_BYTES, PacketBatch, dead_batch,
+                               gather_rows, make_udp_batch)
 
 # Digitized bimodal enterprise distribution (paper Fig. 6).  30 % of packets
 # are below 202 B total (payload < 160 B -> ENB=0), mean ~= 882 B.
@@ -330,10 +330,110 @@ def steer_pipes(
     shards = gather_rows(pkts, src_of)
     shards = jax.tree.map(
         lambda a: a.reshape((num_pipes, pipe_capacity) + a.shape[1:]), shards)
-    counts = jnp.sum(onehot, axis=0)
-    stats = dict(
-        per_pipe_arrivals=[int(c) for c in counts],
-        overflow=int(jnp.sum(~ok)),
-        pipe_capacity=pipe_capacity,
-    )
-    return shards, stats
+    return shards, steer_stats(jnp.sum(onehot, axis=0), pipe_capacity)
+
+
+# --------------------------------------------------------------------------
+# Per-shard steering and exchange (DESIGN.md §12)
+# --------------------------------------------------------------------------
+
+def steer_stats(counts, pipe_capacity: int) -> dict:
+    """``steer_pipes``'s stats from every pipe's arrivals: arrivals beyond
+    ``pipe_capacity`` are the overflow."""
+    counts = [int(c) for c in np.asarray(counts)]
+    return dict(per_pipe_arrivals=counts,
+                overflow=sum(max(c - pipe_capacity, 0) for c in counts),
+                pipe_capacity=pipe_capacity)
+
+
+def steer_shard(pkts: PacketBatch, num_pipes: int, axis: str):
+    """``steer_pipes``'s decision for this device's shard of a flat batch
+    sharded contiguously along its packet axis over mesh axis ``axis``;
+    called inside ``shard_map``.
+
+    Returns ``(pipe, pos, counts)``: each local packet's pipe (its flow
+    hash modulo ``num_pipes``; 0 for one pipe, as ``to_time_major``
+    keeps arrival order) and its arrival index inside that pipe over the
+    whole batch, and every pipe's arrivals over the whole batch (the same
+    on every device).  Only the per-pipe counts cross devices."""
+    b = pkts.batch_size
+    pipe = (flow_hash(pkts) % num_pipes if num_pipes > 1
+            else jnp.zeros((b,), jnp.int32))
+    onehot = pipe[:, None] == jnp.arange(num_pipes)[None, :]   # (b, P)
+    every = jax.lax.all_gather(jnp.sum(onehot, axis=0, dtype=jnp.int32),
+                               axis)                           # (D, P)
+    earlier = jnp.arange(every.shape[0]) < jax.lax.axis_index(axis)
+    before = jnp.sum(jnp.where(earlier[:, None], every, 0), axis=0)
+    rank = jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1
+    pos = before[pipe] + jnp.take_along_axis(rank, pipe[:, None], axis=1)[:, 0]
+    return pipe, pos, jnp.sum(every, axis=0)
+
+
+def pack_rows(p: PacketBatch) -> tuple[jax.Array, jax.Array]:
+    """A flat batch as rows of two arrays: the header fields as one
+    (B, 15) int32 row per packet, and the (B, pmax) payload bytes."""
+    heads = [getattr(p, f.name).astype(jnp.int32)
+             for f in dataclasses.fields(PacketBatch) if f.name != "payload"]
+    return jnp.stack(heads, axis=1), p.payload
+
+
+def unpack_rows(rows: tuple[jax.Array, jax.Array]) -> PacketBatch:
+    """Inverse of ``pack_rows``; an all-zero row is a dead packet."""
+    heads, payload = rows
+    names = [f.name for f in dataclasses.fields(PacketBatch)
+             if f.name != "payload"]
+    like = dead_batch(1, 1)
+    return PacketBatch(payload=payload, **{
+        n: heads[:, i].astype(getattr(like, n).dtype)
+        for i, n in enumerate(names)})
+
+
+def exchange_rows(rows, dest: jax.Array, slot: jax.Array, slots: int,
+                  axis: str, devices: int):
+    """Move each of this device's rows to slot ``slot`` of device
+    ``dest`` along mesh axis ``axis`` of ``devices`` devices (a ``dest``
+    of ``devices`` moves the row nowhere); called inside ``shard_map``.
+    ``rows`` is a pytree of arrays whose leading axis is the row.
+    Returns the ``slots`` rows this device received, zero where none
+    arrived.
+
+    Rows travel in rounds of one ``all_to_all`` per leaf, at most ``m``
+    rows from each device to each other, in the order they are held; the
+    rounds go on until every row has arrived.  ``m`` is a sixteenth above
+    half the even share, so hash-balanced traffic takes two rounds, and a
+    round's buffers hold a little over half this device's rows; skewed
+    traffic takes more rounds."""
+    n = dest.shape[0]
+    m = -(-(n + n // 16) // (2 * devices))
+    m = -(-m // 8) * 8
+    onehot = dest[:, None] == jnp.arange(devices)[None, :]      # (n, D)
+    rank = jnp.take_along_axis(
+        jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1,
+        jnp.minimum(dest, devices - 1)[:, None], axis=1)[:, 0]
+    most = jax.lax.pmax(jnp.max(jnp.sum(onehot, axis=0, dtype=jnp.int32)),
+                        axis)
+
+    def send(a, src, fill=0):
+        # row ``src``, or ``fill`` for a src past the rows
+        return jax.lax.all_to_all(a.at[src].get(mode="fill", fill_value=fill),
+                                  axis, 0, 0, tiled=True)
+
+    def one_round(k, out):
+        lo = k * m
+        go = (dest < devices) & (rank >= lo) & (rank < lo + m)
+        at = jnp.where(go, dest * m + rank - lo, devices * m)
+        src = jnp.full((devices * m,), n, jnp.int32).at[at].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+        into = jnp.full((slots,), devices * m, jnp.int32).at[
+            send(slot, src, slots)].set(
+                jnp.arange(devices * m, dtype=jnp.int32), mode="drop")
+        came = into < devices * m
+        return jax.tree.map(
+            lambda a, o: jnp.where(
+                came.reshape((slots,) + (1,) * (o.ndim - 1)),
+                send(a, src).at[into].get(mode="fill", fill_value=0), o),
+            rows, out)
+
+    out = jax.tree.map(lambda a: jnp.zeros((slots,) + a.shape[1:], a.dtype),
+                       rows)
+    return jax.lax.fori_loop(0, (most + m - 1) // m, one_round, out)

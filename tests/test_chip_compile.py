@@ -148,3 +148,34 @@ def test_fabric_engine_compiles_for_v5e_2x2(topo, monkeypatch):
     for op in ("all-gather", "all-reduce", "collective-permute",
                "all-to-all"):
         assert op not in text, op
+
+
+def test_fabric_traffic_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The per-shard traffic program of the 64-pipe fabric (8 ToRs, 16
+    pipes a chip, 1,048,576 packets a call) on the four chips of a v5e
+    2x2: each chip's working memory is less than the whole call's
+    payload, its output (its pipes' traces) a quarter of it with the
+    steering slack, and the packets cross chips by ``all_to_all``."""
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from repro.scenarios import spec as S
+    from repro.switchsim import fabric
+
+    mesh = Mesh(topo.devices[:4], (fabric.SWITCH_AXIS,),
+                axis_types=(AxisType.Auto,))
+    monkeypatch.setattr(fabric, "switch_mesh", lambda devices: mesh)
+    spec = ScenarioSpec(
+        name="fabric", workload=("datacenter",), chain=("fw", "nat", "lb"),
+        pipes=64, recirc=True, capacity=CAPACITY, max_exp=4, flows=10000,
+        fw_rules=20, packets=64 * 16384, chunk=CHUNK, window=2, pmax=2048,
+        backend="pallas", devices=4)
+    fn = S._traffic_program.__wrapped__((S._traffic_shape(spec),), 4)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = fn.lower(jax.ShapeDtypeStruct(
+        key.shape, key.dtype,
+        sharding=NamedSharding(mesh, PartitionSpec()))).compile()
+    mem = compiled.memory_analysis()
+    whole = spec.packets * spec.pmax
+    assert mem.temp_size_in_bytes < whole
+    assert mem.output_size_in_bytes < whole * 1.25 * 1.05 / 4
+    assert "all-to-all" in compiled.as_text()
